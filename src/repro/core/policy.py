@@ -47,7 +47,7 @@ class KernelConfig:
 
     ``use_pallas=True`` routes every decode matmul over a *packed*
     :class:`~repro.core.ttq.QuantizedTensor` through the fused Pallas
-    ``ttq_gemm`` (in-kernel unpack + dequant + D⁻¹ prologue) instead of the
+    ``ttq_gemm`` (in-kernel unpack + dequant) instead of the
     jnp dequantize-then-einsum fallback.  Weights without a packed payload
     (``policy.packed=False``, unpackable bit-widths) always take the
     fallback, so the flag is a pure opt-in.

@@ -31,26 +31,30 @@ import jax.numpy as jnp
 from .awq import AWQConfig, awq_quantize, diag_from_stats
 from .lowrank import svd_factors
 from .policy import QuantPolicy
-from .qdq import QuantConfig, dequantize, pack_bits, unpack_bits
+from .qdq import pack_bits, unpack_bits
 
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class QuantizedTensor:
-    """Groupwise-quantized weight (row layout): y = deq(Wint)·(x/D) [+ B(Ax)].
+    """Groupwise-quantized weight: y = (x/D)·deq(Wint) [+ B(Ax)].
 
-    ``packed`` holds int32 nibble-packed data (d', d·bits/32) when the policy's
-    packed path is on, else ``wint`` holds **uint8** codes in [0, 2^bits−1].
-    Exactly one of the two is set.  Codes are unsigned on purpose: 8-bit
-    codes span 0..255, which a signed int8 store would wrap — unpacked-on-
-    the-fly codes stay int32 for the same reason (bits=8 round-trip
-    regression in tests/test_fused_path.py).
+    Codes and scales are stored **K-major** — the contraction dim d leads —
+    so the Pallas ``ttq_gemm`` streams (bk/per, bn) code tiles and
+    (bk/g, bn) scale tiles that obey the TPU's (8, 128) block tiling at
+    real widths.  ``packed`` holds int32 data (d·bits/32, d'), ``32//bits``
+    consecutive k-rows per int32 (:func:`pack_weight`), when the policy's
+    packed path is on, else ``wint`` holds **uint8** codes (d, d') in
+    [0, 2^bits−1].  Exactly one of the two is set.  Codes are unsigned on
+    purpose: 8-bit codes span 0..255, which a signed int8 store would wrap
+    — unpacked-on-the-fly codes stay int32 for the same reason (bits=8
+    round-trip regression in tests/test_fused_path.py).
     """
 
-    wint: Optional[jnp.ndarray]      # (d', d) uint8 | None
-    packed: Optional[jnp.ndarray]    # (d', d*bits//32) int32 | None
-    scale: jnp.ndarray               # (d', d//g) f32
-    zero: jnp.ndarray                # (d', d//g) f32
+    wint: Optional[jnp.ndarray]      # (d, d') uint8 | None
+    packed: Optional[jnp.ndarray]    # (d*bits//32, d') int32 | None
+    scale: jnp.ndarray               # (d//g, d') f32
+    zero: jnp.ndarray                # (d//g, d') f32
     dinv: jnp.ndarray                # (d,) f32 — activation prescale 1/D
     B: Optional[jnp.ndarray]         # (d', r) | None
     A: Optional[jnp.ndarray]         # (r, d) | None
@@ -69,9 +73,34 @@ class QuantizedTensor:
     def tree_unflatten(cls, aux, children):
         return cls(*children, *aux)
 
-    @property
-    def qcfg(self) -> QuantConfig:
-        return QuantConfig(bits=self.bits, group_size=self.group_size, layout="row")
+
+def packable(bits: int, d: int) -> bool:
+    """Whether ``bits``-bit codes of a d-wide input pack into int32 rows."""
+    return 32 % bits == 0 and d % (32 // bits) == 0
+
+
+def pack_weight(wint: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """Row-layout codes (..., d', d) → K-major packed (..., d·bits/32, d')
+    int32: int32 row i holds k-rows ``i·per .. i·per+per−1`` (per =
+    32//bits), lowest bits first."""
+    return pack_bits(wint.astype(jnp.int32), bits).swapaxes(-1, -2)
+
+
+def unpack_weight(packed: jnp.ndarray, d: int, bits: int) -> jnp.ndarray:
+    """Inverse of :func:`pack_weight`: (..., d·bits/32, d') → (..., d, d')
+    int32."""
+    return unpack_bits(packed.swapaxes(-1, -2), d, bits).swapaxes(-1, -2)
+
+
+def dequantize_kmajor(wint: jnp.ndarray, scale: jnp.ndarray,
+                      zero: jnp.ndarray, group_size: int) -> jnp.ndarray:
+    """K-major codes (d, d') with (d/g, d') scale/zero → f32 (d, d').  Only
+    the leading (contraction) dim is split, so a weight sharded on d' never
+    needs gathering to dequantize."""
+    d, dp = wint.shape
+    g = group_size
+    w = wint.reshape(d // g, g, dp).astype(jnp.float32)
+    return (w * scale[:, None, :] + zero[:, None, :]).reshape(d, dp)
 
 
 def calibrate(stats: Any, counts: Any, acfg: AWQConfig) -> Any:
@@ -94,12 +123,12 @@ def quantize_weight(W: jnp.ndarray, D: jnp.ndarray, policy: QuantPolicy,
     wint, S, Z = awq_quantize(Wf, D, qcfg)
     dinv = (1.0 / D).astype(jnp.float32)
     packed = wint_out = None
-    if policy.packed and (32 % qcfg.bits == 0) and (W.shape[1] % (32 // qcfg.bits) == 0):
-        packed = pack_bits(wint.astype(jnp.int32), qcfg.bits)
+    if policy.packed and packable(qcfg.bits, W.shape[1]):
+        packed = pack_weight(wint, qcfg.bits)
     else:
-        wint_out = wint
+        wint_out = wint.T
     return QuantizedTensor(
-        wint=wint_out, packed=packed, scale=S, zero=Z, dinv=dinv, B=B, A=A,
+        wint=wint_out, packed=packed, scale=S.T, zero=Z.T, dinv=dinv, B=B, A=A,
         bits=qcfg.bits, group_size=qcfg.group_size,
         out_features=W.shape[0], in_features=W.shape[1],
     )
@@ -125,29 +154,33 @@ def init_lowrank_tree(params: Any, policy: QuantPolicy, is_weight) -> Any:
 
 def dequant(qt: QuantizedTensor) -> jnp.ndarray:
     """Effective fp weight  Ŵ = deq(Wint)∘D⁻¹ [+ BA]  — reference/debug path."""
-    wint = qt.wint
-    if wint is None:
-        # keep unpacked codes in int32: 8-bit codes span 0..255, which
-        # overflows a signed int8 cast (the historical hazard) — int32 is
-        # what unpack_bits yields and dequantize only needs a float cast
-        wint = unpack_bits(qt.packed, qt.in_features, qt.bits)
-    Wd = dequantize(wint, qt.scale, qt.zero, qt.qcfg)
-    W = Wd * qt.dinv[None, :]
+    W = _codes_f32(qt).T * qt.dinv[None, :]
     if qt.B is not None:
         W = W + qt.B.astype(jnp.float32) @ qt.A.astype(jnp.float32)
     return W
 
 
+def _codes_f32(qt: QuantizedTensor) -> jnp.ndarray:
+    """Dequantized K-major weight deq(Wint) (d, d') f32, D⁻¹ not applied.
+    Packed codes unpack to int32: 8-bit codes span 0..255, which a signed
+    int8 cast would wrap."""
+    wint = qt.wint
+    if wint is None:
+        wint = unpack_weight(qt.packed, qt.in_features, qt.bits)
+    return dequantize_kmajor(wint, qt.scale, qt.zero, qt.group_size)
+
+
 def ttq_matmul(x: jnp.ndarray, qt: QuantizedTensor, *,
                use_kernel: bool = False, kcfg=None,
-               precision=None, pctx=None, tp=None) -> jnp.ndarray:
+               pctx=None, tp=None) -> jnp.ndarray:
     """y = x @ Ŵᵀ for x: (..., d).  Kernel path uses the Pallas ttq_gemm.
 
     ``kcfg`` (:class:`~repro.core.policy.KernelConfig`) is the policy-driven
     dispatch switch threaded by the model stack: ``use_pallas=True`` (or the
-    legacy ``use_kernel`` flag) sends packed weights through ``ttq_gemm``
-    with the D⁻¹ prescale fused into the kernel prologue.  The jnp fallback
-    prescales x∘D⁻¹ on the (small) activation; the low-rank branch runs in
+    legacy ``use_kernel`` flag) sends packed weights through ``ttq_gemm``.
+    Both paths prescale x∘D⁻¹ in f32 on the (small) activation and feed
+    the dot operands (x∘D⁻¹ and the dequantized weight) in x's dtype with
+    f32 accumulation — the fp path's precision; the low-rank branch runs in
     fp on the *unscaled* x either way (BA was subtracted before scaling).
 
     ``pctx``/``tp``: with an active mesh and a TP role hint ('row'|'col')
@@ -164,24 +197,25 @@ def ttq_matmul(x: jnp.ndarray, qt: QuantizedTensor, *,
                              bits=qt.bits, group_size=qt.group_size,
                              pctx=pctx, tp=tp, **kw)
     else:
-        # f32 prescale + accumulation over the same flattened (T, d)×(d, d')
-        # gemm shape the kernel presents, so both paths hit the same backend
-        # micro-kernel and the same f32 reduction order (the greedy-equality
-        # contract: flipping the kernel on must not move a single token);
-        # the cast back to x.dtype mirrors ttq_gemm's epilogue
+        # the kernel's operands over the same flattened (T, d)×(d, d') gemm
+        # shape it presents, so in interpret mode both paths hit the same
+        # backend micro-kernel and the same f32 reduction order (the
+        # greedy-equality contract: flipping the kernel on must not move a
+        # single token); the cast back to x.dtype mirrors ttq_gemm's epilogue
         lead = x.shape[:-1]
         xs = x.reshape(-1, x.shape[-1]).astype(jnp.float32) * qt.dinv
-        wint = qt.wint
-        if wint is None:
-            wint = unpack_bits(qt.packed, qt.in_features, qt.bits)
-        Wd = dequantize(wint, qt.scale, qt.zero, qt.qcfg)
-        y = jax.lax.dot_general(xs, Wd, (((1,), (1,)), ((), ())),
-                                precision=precision,
+        y = jax.lax.dot_general(xs.astype(x.dtype),
+                                _codes_f32(qt).astype(x.dtype),
+                                (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         y = y.reshape(*lead, -1).astype(x.dtype)
     if qt.B is not None:
-        y = y + jnp.einsum("...r,or->...o", jnp.einsum("...d,rd->...r", x, qt.A.astype(x.dtype)),
-                           qt.B.astype(x.dtype))
+        # f32 results, then the casts: a d-sharded x (column-parallel TP)
+        # reduces its partial sums in f32, as the single-device dot does
+        xa = jnp.einsum("...d,rd->...r", x, qt.A.astype(x.dtype),
+                        preferred_element_type=jnp.float32).astype(x.dtype)
+        y = y + jnp.einsum("...r,or->...o", xa, qt.B.astype(x.dtype),
+                           preferred_element_type=jnp.float32).astype(x.dtype)
     return y
 
 

@@ -26,14 +26,15 @@ _KV_BITS = (4, 8)
 
 
 def ttq_gemm(x, packed, scale, zero, dinv=None, *, bits=4, group_size=32,
-             use_pallas=True, **block_kw):
+             use_pallas=True, out_dtype=None, **block_kw):
     if use_pallas and bits in _PACKABLE:
         return _ttq_gemm_pallas(x, packed, scale, zero, dinv, bits=bits,
-                                group_size=group_size, **block_kw)
+                                group_size=group_size, out_dtype=out_dtype,
+                                **block_kw)
     lead = x.shape[:-1]
     y = _ref.ttq_gemm_ref(x.reshape(-1, x.shape[-1]), packed, scale, zero,
                           bits=bits, group_size=group_size, dinv=dinv)
-    return y.reshape(*lead, -1).astype(x.dtype)
+    return y.reshape(*lead, -1).astype(out_dtype or x.dtype)
 
 
 def kv_decode_attention(q, kq, ks, vq, vs, cur_pos, *, bits=8, group_size=0,
@@ -136,12 +137,12 @@ def _tp_gemm_ok(pctx, tp, x, packed, scale, bits, group_size):
     if n <= 1 or x.shape[0] % ndp:
         return False
     if tp == "row":
-        return packed.shape[0] % n == 0 and scale.shape[0] % n == 0
+        return packed.shape[1] % n == 0 and scale.shape[1] % n == 0
     d = x.shape[-1]
     per = 32 // bits
     g = group_size or d
     return (d % n == 0 and (d // n) % g == 0 and (d // n) % per == 0
-            and packed.shape[1] % n == 0 and scale.shape[1] % n == 0)
+            and packed.shape[0] % n == 0 and scale.shape[0] % n == 0)
 
 
 def ttq_gemm_tp(x, packed, scale, zero, dinv=None, *,  # tracecheck: ok[TC303]
@@ -150,38 +151,90 @@ def ttq_gemm_tp(x, packed, scale, zero, dinv=None, *,  # tracecheck: ok[TC303]
     """``ttq_gemm`` with Megatron-style tensor parallelism.
 
     ``tp='row'``: output features sharded on the model axis — each device
-    multiplies against its (d'/n, d) shard, no collective, output stays
-    sharded.  ``tp='col'``: input features sharded — each device consumes its
-    x shard against a (d', d/n) weight slice and a psum over the model axis
-    rebuilds the full output.  Ineligible shapes use the unwrapped dispatch
-    (GSPMD partitions or replicates it).
+    multiplies against its (d, d'/n) K-major shard, no collective, output
+    stays sharded.  ``tp='col'``: input features sharded — each device
+    consumes its x shard against a (d/n, d') weight slice and a psum over
+    the model axis rebuilds the full output.  Ineligible shapes use the
+    unwrapped dispatch (GSPMD partitions or replicates it).
     """
     gemm = partial(ttq_gemm, bits=bits, group_size=group_size,
                    use_pallas=use_pallas, **block_kw)
     if not _tp_gemm_ok(pctx, tp, x, packed, scale, bits, group_size):
         return gemm(x, packed, scale, zero, dinv)
-    from repro.parallel.compat import shard_map
     P = jax.sharding.PartitionSpec
     m, dp = pctx.model_axis, pctx.dp
     lead = [None] * (x.ndim - 2)
     if dinv is None:
         dinv = jnp.ones((x.shape[-1],), jnp.float32)
     if tp == "row":
-        in_specs = (P(dp, *lead, None), P(m, None), P(m, None), P(m, None),
+        in_specs = (P(dp, *lead, None), P(None, m), P(None, m), P(None, m),
                     P(None))
         out_specs = P(dp, *lead, m)
 
         def fn(xx, pk, sc, zr, dv):
             return gemm(xx, pk, sc, zr, dv)
     else:
-        in_specs = (P(dp, *lead, m), P(None, m), P(None, m), P(None, m), P(m))
+        in_specs = (P(dp, *lead, m), P(m, None), P(m, None), P(m, None), P(m))
         out_specs = P(dp, *lead, None)
 
         def fn(xx, pk, sc, zr, dv):
-            return jax.lax.psum(gemm(xx, pk, sc, zr, dv), m)
-    return shard_map(fn, mesh=pctx.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)(
+            # f32 partial products (the kernel accumulates in f32 anyway),
+            # summed across shards before the cast — as one device would
+            y = gemm(xx, pk, sc, zr, dv, out_dtype=jnp.float32)
+            return jax.lax.psum(y, m).astype(xx.dtype)
+    return jax.shard_map(fn, mesh=pctx.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(
         x, packed, scale, zero, dinv)
+
+
+def tp_quantize_ok(pctx, tp, W, *, bits, group_size):
+    """Whether :func:`ttq_quantize_tp` can run the kernel for a stacked
+    (N, d', d) family on ``pctx``'s mesh: a row family needs d' to divide the
+    model axis; a column family needs d to, with every local slice group-
+    and pack-aligned so the K-major scale/zero/code rows split with it;
+    a replicated family (``tp=None``) always can.  Expert (EP) stacks
+    cannot: their leading sharded dim is merged into N."""
+    n, _ = _mesh_sizes(pctx)
+    if n <= 1:
+        return True
+    dp, d = W.shape[-2:]
+    if tp == "row":
+        return dp % n == 0
+    if tp == "col":
+        return (d % n == 0 and (d // n) % group_size == 0
+                and (d // n) % (32 // bits) == 0)
+    return tp is None
+
+
+def ttq_quantize_tp(W, D, *, bits=4, group_size=32, pctx=None, tp=None,
+                    **block_kw):
+    """The Pallas ``ttq_quantize`` over a stacked (N, d', d) family with
+    D (N, d) → K-major (packed (N, d·bits/32, d'), S, Z (N, d/g, d')).
+
+    On a mesh (a Mosaic kernel cannot be partitioned by GSPMD) the call is
+    shard_map'd over the family's own weight layout (``parallel/rules.py``):
+    ``tp='row'`` quantizes each device's d'/n output rows, whose codes stay
+    d'-sharded; ``tp='col'`` quantizes each device's d/n input columns with
+    their slice of D, whose code/scale rows stay d-sharded; ``tp=None``
+    (replicated) quantizes the whole stack on every device.  The caller
+    checks :func:`tp_quantize_ok` first."""
+    quant = jax.vmap(partial(ttq_quantize, bits=bits, group_size=group_size,
+                             **block_kw))
+    n, _ = _mesh_sizes(pctx)
+    if n <= 1:
+        return quant(W, D)
+    if not tp_quantize_ok(pctx, tp, W, bits=bits, group_size=group_size):
+        raise ValueError(f"{W.shape} family cannot be quantized shard-"
+                         f"locally as tp={tp!r}")
+    P = jax.sharding.PartitionSpec
+    m = pctx.model_axis
+    w_spec, d_spec, out_spec = {
+        "row": (P(None, m, None), P(None, None), P(None, None, m)),
+        "col": (P(None, None, m), P(None, m), P(None, m, None)),
+        None: (P(), P(), P()),
+    }[tp]
+    return jax.shard_map(quant, mesh=pctx.mesh, in_specs=(w_spec, d_spec),
+                         out_specs=(out_spec,) * 3, check_vma=False)(W, D)
 
 
 def _tp_attn_ok(pctx, q, kq, batched_cache):
@@ -199,13 +252,12 @@ def kv_decode_attention_tp(q, kq, ks, vq, vs, cur_pos, *, pctx=None, **kw):
     call = partial(kv_decode_attention, **kw)
     if not _tp_attn_ok(pctx, q, kq, True):
         return call(q, kq, ks, vq, vs, cur_pos)
-    from repro.parallel.compat import shard_map
     P = jax.sharding.PartitionSpec
     m, dp = pctx.model_axis, pctx.dp
     hs = P(dp, m, None, None)
-    return shard_map(lambda *a: call(*a), mesh=pctx.mesh,
-                     in_specs=(hs, hs, hs, hs, hs, P(dp)), out_specs=hs,
-                     check_vma=False)(q, kq, ks, vq, vs, cur_pos)
+    return jax.shard_map(lambda *a: call(*a), mesh=pctx.mesh,
+                         in_specs=(hs, hs, hs, hs, hs, P(dp)), out_specs=hs,
+                         check_vma=False)(q, kq, ks, vq, vs, cur_pos)
 
 
 def kv_suffix_attention_tp(q, kq, ks, vq, vs, pos, *, pctx=None, **kw):
@@ -215,13 +267,12 @@ def kv_suffix_attention_tp(q, kq, ks, vq, vs, pos, *, pctx=None, **kw):
     call = partial(kv_suffix_attention, **kw)
     if not _tp_attn_ok(pctx, q, kq, True):
         return call(q, kq, ks, vq, vs, pos)
-    from repro.parallel.compat import shard_map
     P = jax.sharding.PartitionSpec
     m, dp = pctx.model_axis, pctx.dp
     hs = P(dp, m, None, None)
-    return shard_map(lambda *a: call(*a), mesh=pctx.mesh,
-                     in_specs=(hs, hs, hs, hs, hs, P(dp)), out_specs=hs,
-                     check_vma=False)(q, kq, ks, vq, vs, pos)
+    return jax.shard_map(lambda *a: call(*a), mesh=pctx.mesh,
+                         in_specs=(hs, hs, hs, hs, hs, P(dp)), out_specs=hs,
+                         check_vma=False)(q, kq, ks, vq, vs, pos)
 
 
 def kv_paged_suffix_attention_tp(q, kq, ks, vq, vs, block_table, pos, *,
@@ -232,14 +283,14 @@ def kv_paged_suffix_attention_tp(q, kq, ks, vq, vs, block_table, pos, *,
     call = partial(kv_paged_suffix_attention, **kw)
     if not _tp_attn_ok(pctx, q, kq, False):
         return call(q, kq, ks, vq, vs, block_table, pos)
-    from repro.parallel.compat import shard_map
     P = jax.sharding.PartitionSpec
     m, dp = pctx.model_axis, pctx.dp
     qs = P(dp, m, None, None)
     pool = P(None, m, None, None)
-    return shard_map(lambda *a: call(*a), mesh=pctx.mesh,
-                     in_specs=(qs, pool, pool, pool, pool, P(dp, None), P(dp)),
-                     out_specs=qs, check_vma=False)(
+    return jax.shard_map(lambda *a: call(*a), mesh=pctx.mesh,
+                         in_specs=(qs, pool, pool, pool, pool, P(dp, None),
+                                   P(dp)),
+                         out_specs=qs, check_vma=False)(
         q, kq, ks, vq, vs, block_table, pos)
 
 
@@ -251,12 +302,12 @@ def kv_paged_decode_attention_tp(q, kq, ks, vq, vs, block_table, cur_pos, *,
     call = partial(kv_paged_decode_attention, **kw)
     if not _tp_attn_ok(pctx, q, kq, False):
         return call(q, kq, ks, vq, vs, block_table, cur_pos)
-    from repro.parallel.compat import shard_map
     P = jax.sharding.PartitionSpec
     m, dp = pctx.model_axis, pctx.dp
     qs = P(dp, m, None, None)
     pool = P(None, m, None, None)
-    return shard_map(lambda *a: call(*a), mesh=pctx.mesh,
-                     in_specs=(qs, pool, pool, pool, pool, P(dp, None), P(dp)),
-                     out_specs=qs, check_vma=False)(
+    return jax.shard_map(lambda *a: call(*a), mesh=pctx.mesh,
+                         in_specs=(qs, pool, pool, pool, pool, P(dp, None),
+                                   P(dp)),
+                         out_specs=qs, check_vma=False)(
         q, kq, ks, vq, vs, block_table, cur_pos)

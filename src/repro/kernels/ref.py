@@ -1,30 +1,50 @@
 """Pure-jnp oracles for the Pallas kernels (the allclose targets in tests)."""
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
-from repro.core.qdq import unpack_bits
 
 
+@partial(jax.jit, static_argnames=("bits", "group_size"))
 def ttq_gemm_ref(x: jnp.ndarray, packed: jnp.ndarray, scale: jnp.ndarray,
                  zero: jnp.ndarray, *, bits: int, group_size: int,
                  dinv: jnp.ndarray | None = None) -> jnp.ndarray:
-    """y (T, d') = x (T, d) [∘dinv] @ deq(packed (d', d·bits/32), S, Z)ᵀ, f32 accum."""
-    dp, _ = packed.shape[0], packed.shape[1]
+    """y (T, d') = x (T, d) [∘dinv] @ deq(packed (d·bits/32, d'),
+    S, Z (d/g, d')).  The kernel's operand contract: x∘dinv and the
+    dequantized weight rounded to x's dtype, products accumulated in f32 —
+    here at full f32 precision, so only the accumulation order differs.
+    Jitted, so the dequantization compiles as the kernel's does (a fused
+    multiply-add or not decides the last f32 bit, and with it, rarely, a
+    bf16 step of one weight)."""
+    from repro.core.ttq import dequantize_kmajor, unpack_weight
     d = x.shape[-1]
-    wint = unpack_bits(packed, d, bits).astype(jnp.float32)          # (d', d)
-    g = group_size
-    s = jnp.repeat(scale.astype(jnp.float32), g, axis=1)             # (d', d)
-    z = jnp.repeat(zero.astype(jnp.float32), g, axis=1)
-    W = wint * s + z
+    W = dequantize_kmajor(unpack_weight(packed, d, bits), scale, zero,
+                          group_size)                               # (d, d')
     xf = x.astype(jnp.float32)
     if dinv is not None:
         xf = xf * dinv[None, :].astype(jnp.float32)
-    return xf @ W.T
+    return jnp.dot(_round(xf, x.dtype), _round(W, x.dtype),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _round(a: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``a`` rounded to ``dtype``, held in f32 (an MXU operand, exactly)."""
+    return a.astype(dtype).astype(jnp.float32)
 
 
 NEG_INF = -1e30
+
+
+def _kv_operands(kq, ks, vq, vs, bits, group_size, dtype):
+    """Dequantized k, v (B, Hkv, S, Dh), rounded to ``dtype`` (held in
+    f32)."""
+    from repro.core.kvquant import dequantize_kv
+    return tuple(_round(dequantize_kv(c, sc, jnp.float32, bits=bits,
+                                      group_size=group_size), dtype)
+                 for c, sc in ((kq, ks), (vq, vs)))
 
 
 def kv_attn_ref(q: jnp.ndarray, kq: jnp.ndarray, ks: jnp.ndarray,
@@ -34,18 +54,19 @@ def kv_attn_ref(q: jnp.ndarray, kq: jnp.ndarray, ks: jnp.ndarray,
                 window: int = 0) -> jnp.ndarray:
     """Decode attention over a quantized cache: dequantize, then the same
     grouped-query math as ``models.common.decode_attention`` (f32 softmax).
+    The scaled q and the dequantized k/v are rounded to q's dtype, as the
+    kernel feeds them to the MXU; the softmax weights stay f32.
 
     q: (B,H,1,Dh); kq/vq codes (B,Hkv,S,Dc); ks/vs scales (B,Hkv,S,Dh//g);
     cur_pos: (B,) int32.  The allclose target for ``ttq_attn``.
     """
-    from repro.core.kvquant import dequantize_kv
     B, H, _, Dh = q.shape
     Hkv, S = kq.shape[1], kq.shape[2]
     G = H // Hkv
     sc = scale if scale is not None else Dh ** -0.5
-    k = dequantize_kv(kq, ks, jnp.float32, bits=bits, group_size=group_size)
-    v = dequantize_kv(vq, vs, jnp.float32, bits=bits, group_size=group_size)
-    qg = (q[:, :, 0].astype(jnp.float32) * sc).reshape(B, Hkv, G, Dh)
+    k, v = _kv_operands(kq, ks, vq, vs, bits, group_size, q.dtype)
+    qg = _round(q[:, :, 0].astype(jnp.float32) * sc,
+                q.dtype).reshape(B, Hkv, G, Dh)
     s = jnp.einsum("bhgd,bhkd->bhgk", qg, k)
     if soft_cap > 0:
         s = soft_cap * jnp.tanh(s / soft_cap)
@@ -72,14 +93,12 @@ def kv_suffix_attn_ref(q: jnp.ndarray, kq: jnp.ndarray, ks: jnp.ndarray,
     dequantize-then-grouped-query math as :func:`kv_attn_ref` with a query
     axis, so verify logits match sequential decode bit-for-bit.
     """
-    from repro.core.kvquant import dequantize_kv
     B, H, S, Dh = q.shape
     Hkv, Smax = kq.shape[1], kq.shape[2]
     G = H // Hkv
     sc = scale if scale is not None else Dh ** -0.5
-    k = dequantize_kv(kq, ks, jnp.float32, bits=bits, group_size=group_size)
-    v = dequantize_kv(vq, vs, jnp.float32, bits=bits, group_size=group_size)
-    qg = (q.astype(jnp.float32) * sc).reshape(B, Hkv, G, S, Dh)
+    k, v = _kv_operands(kq, ks, vq, vs, bits, group_size, q.dtype)
+    qg = _round(q.astype(jnp.float32) * sc, q.dtype).reshape(B, Hkv, G, S, Dh)
     s = jnp.einsum("bhgsd,bhkd->bhgsk", qg, k)
     if soft_cap > 0:
         s = soft_cap * jnp.tanh(s / soft_cap)
@@ -139,10 +158,12 @@ def kv_paged_attn_ref(q: jnp.ndarray, kq: jnp.ndarray, ks: jnp.ndarray,
 
 def ttq_quantize_ref(W: jnp.ndarray, D: jnp.ndarray, *, bits: int,
                      group_size: int):
-    """Online scaled groupwise quantize+pack.
+    """Online scaled groupwise quantize+pack, K-major outputs.
 
-    W (d', d), D (d,) → packed (d', d·bits/32) int32, S (d', d/g) f32, Z (d', d/g) f32.
+    W (d', d), D (d,) → packed (d·bits/32, d') int32, S (d/g, d') f32,
+    Z (d/g, d') f32 (the :class:`~repro.core.ttq.QuantizedTensor` layout).
     """
+    from repro.core.ttq import pack_weight
     qmax = (1 << bits) - 1
     g = group_size
     dp, d = W.shape
@@ -153,8 +174,4 @@ def ttq_quantize_ref(W: jnp.ndarray, D: jnp.ndarray, *, bits: int,
     S = jnp.maximum((wmax - wmin) / qmax, 1e-12)
     Z = wmin
     wint = jnp.clip(jnp.round((Wg - Z[..., None]) / S[..., None]), 0, qmax)
-    wint = wint.reshape(dp, d).astype(jnp.int32)
-    per = 32 // bits
-    shifts = jnp.arange(per, dtype=jnp.int32) * bits
-    packed = (wint.reshape(dp, d // per, per) << shifts).sum(axis=-1)
-    return packed, S, Z
+    return pack_weight(wint.reshape(dp, d), bits), S.T, Z.T

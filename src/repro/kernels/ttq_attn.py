@@ -13,14 +13,17 @@ kernel:
   VPU       unpack nibbles (shift+mask, int4 only), dequantize to f32 with
             the groupwise scale broadcast — the cache is NEVER materialized
             at bf16 in HBM
-  MXU       (G, Dh) @ (Dh, bs) scores; online-softmax accumulate into a
-            (G, Dh) f32 output tile (flash-decoding over the S axis)
+  MXU       (G, Dh) @ (Dh, bs) scores with q and k in q's dtype (bf16 when
+            serving); online-softmax accumulate p·v (v in q's dtype, p as a
+            hi/lo pair of it) into a (G, Dh) f32 output tile
+            (flash-decoding over the S axis)
 
 Grid (B, Hkv, S/bs) with the S axis "arbitrary" (sequential — the running
 max/denominator/accumulator live in VMEM scratch, initialized at s==0 and
-written out at the last tile).  ``cur_pos`` rides in SMEM; slots beyond it
-are masked with an explicit where (NOT exp(-inf - -inf), which would poison
-fully-masked tiles).
+written out at the last tile).  ``cur_pos`` rides in SMEM as a
+scalar-prefetch argument (as does the paged kernel's block table); slots
+beyond it are masked with an explicit where (NOT exp(-inf - -inf), which
+would poison fully-masked tiles).
 
 Validated in interpret mode on CPU (this container) against
 ``ref.kv_attn_ref``; ``ops.kv_decode_attention`` is the public wrapper with
@@ -54,6 +57,31 @@ def _dequant_tile(codes, scales, *, bits: int, group_size: int, Dh: int):
     return w * s
 
 
+def _scores(q, k):
+    """(G, Dh) queries · (bs, Dh) keys → (G, bs) f32: operands in q's dtype
+    (bf16 when serving: one MXU pass, the fp path's precision), f32
+    accumulation."""
+    return jax.lax.dot_general(q, k.astype(q.dtype),
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _weighted_values(p, v, dtype):
+    """(G, bs) f32 softmax weights · (bs, Dh) values → (G, Dh) f32.  The
+    values enter as ``dtype`` (q's); the weights as a hi/lo pair of it (two
+    MXU passes), which carries p to ~16 bits when ``dtype`` is bf16: the
+    unnormalized online-softmax weights are rounded per tile against a
+    running max, so one bf16 rounding of them would not match the oracle's
+    normalized softmax even in order."""
+    v = v.astype(dtype)
+    hi = p.astype(dtype)
+    lo = (p - hi.astype(jnp.float32)).astype(dtype)
+    dims = (((1,), (0,)), ((), ()))
+    return (jax.lax.dot_general(hi, v, dims, preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(lo, v, dims,
+                                  preferred_element_type=jnp.float32))
+
+
 def _attn_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
                  m_ref, l_ref, acc_ref, *, bits: int, group_size: int,
                  soft_cap: float, bs: int, Dh: int, n_s: int):
@@ -65,12 +93,11 @@ def _attn_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    cur = pos_ref[0, 0]
-    q = q_ref[0, 0]                                            # (G, Dh) f32
+    cur = pos_ref[pl.program_id(0)]
+    q = q_ref[0, 0]                                            # (G, Dh)
     k = _dequant_tile(kq_ref[0, 0], ks_ref[0, 0], bits=bits,
                       group_size=group_size, Dh=Dh)            # (bs, Dh)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (G, bs)
+    s = _scores(q, k)                                          # (G, bs)
     if soft_cap > 0:
         s = soft_cap * jnp.tanh(s / soft_cap)
     ki = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -85,8 +112,7 @@ def _attn_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
     l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
     v = _dequant_tile(vq_ref[0, 0], vs_ref[0, 0], bits=bits,
                       group_size=group_size, Dh=Dh)            # (bs, Dh)
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    pv = _weighted_values(p, v, q.dtype)
     acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = m_new
     l_ref[...] = l_new
@@ -117,11 +143,10 @@ def _paged_attn_kernel(bt_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     cur = pos_ref[b]
-    q = q_ref[0, 0]                                            # (G, Dh) f32
+    q = q_ref[0, 0]                                            # (G, Dh)
     k = _dequant_tile(kq_ref[0, 0], ks_ref[0, 0], bits=bits,
                       group_size=group_size, Dh=Dh)            # (bs, Dh)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (G, bs)
+    s = _scores(q, k)                                          # (G, bs)
     if soft_cap > 0:
         s = soft_cap * jnp.tanh(s / soft_cap)
     ki = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -134,8 +159,7 @@ def _paged_attn_kernel(bt_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
     l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
     v = _dequant_tile(vq_ref[0, 0], vs_ref[0, 0], bits=bits,
                       group_size=group_size, Dh=Dh)            # (bs, Dh)
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    pv = _weighted_values(p, v, q.dtype)
     acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = m_new
     l_ref[...] = l_new
@@ -171,7 +195,8 @@ def ttq_paged_decode_attention(q: jnp.ndarray, kq: jnp.ndarray,
     Dc = kq.shape[3]
     nblk = block_table.shape[1]
     sc = scale if scale is not None else Dh ** -0.5
-    qg = (q[:, :, 0].astype(jnp.float32) * sc).reshape(B, Hkv, G, Dh)
+    qg = (q[:, :, 0].astype(jnp.float32) * sc).astype(
+        q.dtype).reshape(B, Hkv, G, Dh)
     bt = jnp.asarray(block_table, jnp.int32)
     pos = jnp.asarray(cur_pos, jnp.int32)
 
@@ -204,10 +229,8 @@ def ttq_paged_decode_attention(q: jnp.ndarray, kq: jnp.ndarray,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(bt, pos, qg, kq, ks, vq, vs)
     return out.reshape(B, H, 1, Dh).astype(q.dtype)
@@ -240,40 +263,42 @@ def ttq_decode_attention(q: jnp.ndarray, kq: jnp.ndarray, ks: jnp.ndarray,
     Gn = ks.shape[3]
     Dc = kq.shape[3]
     sc = scale if scale is not None else Dh ** -0.5
-    qg = (q[:, :, 0].astype(jnp.float32) * sc).reshape(B, Hkv, G, Dh)
+    qg = (q[:, :, 0].astype(jnp.float32) * sc).astype(
+        q.dtype).reshape(B, Hkv, G, Dh)
 
     bs = min(bs, S)
     kq, ks = _pad_seq(kq, bs), _pad_seq(ks, bs)
     vq, vs = _pad_seq(vq, bs), _pad_seq(vs, bs)
     Sp = kq.shape[2]
     n_s = Sp // bs
-    pos2 = jnp.asarray(cur_pos, jnp.int32).reshape(B, 1)
+    pos = jnp.asarray(cur_pos, jnp.int32)
 
     kern = functools.partial(_attn_kernel, bits=bits, group_size=group_size,
                              soft_cap=soft_cap, bs=bs, Dh=Dh, n_s=n_s)
-    out = pl.pallas_call(
-        kern,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                 # cur_pos
         grid=(B, Hkv, n_s),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, s: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, Dh), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, Dc), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs, Gn), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs, Dc), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs, Gn), lambda b, h, s: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, G, Dh), lambda b, h, s, p_r: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, Dc), lambda b, h, s, p_r: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, bs, Gn), lambda b, h, s, p_r: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, bs, Dc), lambda b, h, s, p_r: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, bs, Gn), lambda b, h, s, p_r: (b, h, s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, Dh), lambda b, h, s: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, G, Dh),
+                               lambda b, h, s, p_r: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),       # running max
             pltpu.VMEM((G, 1), jnp.float32),       # running denom
             pltpu.VMEM((G, Dh), jnp.float32),      # output accumulator
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(pos2, qg, kq, ks, vq, vs)
+    )(pos, qg, kq, ks, vq, vs)
     return out.reshape(B, H, 1, Dh).astype(q.dtype)

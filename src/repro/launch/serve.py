@@ -72,7 +72,8 @@ def build_policy(args):
     return policy.with_overrides(*ovr) if ovr else policy
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI (``chip_smoke.py`` parses its engine flags here too)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -171,10 +172,16 @@ def main():
                     help="bound the admission queue: submit() raises "
                          "QueueFull at this depth (the async TTQServer "
                          "awaits instead; 0 = unbounded)")
-    args = ap.parse_args()
+    return ap
 
+
+def build_engine(args, cfg=None, params=None):
+    """Parsed serving flags → :class:`~repro.serving.TTQEngine`.
+
+    ``cfg`` defaults to ``--arch`` (``--smoke`` for the reduced config) and
+    ``params`` to its weights from ``PRNGKey(0)``; a caller that sizes the
+    model itself (``chip_smoke.py``) passes both."""
     import jax
-    import numpy as np
 
     from repro.configs import get
     from repro.models import lm
@@ -184,8 +191,10 @@ def main():
     if args.mesh > 1:
         from repro.launch.mesh import make_ctx, make_mesh
         pctx = make_ctx(make_mesh(1, args.mesh))
-    cfg = get(args.arch, smoke=args.smoke)
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    if cfg is None:
+        cfg = get(args.arch, smoke=args.smoke)
+    if params is None:
+        params = lm.init_params(cfg, jax.random.PRNGKey(0))
     policy = build_policy(args)
     faults = None
     if args.inject:
@@ -217,6 +226,20 @@ def main():
                                  prefill_budget=args.prefill_budget,
                                  max_queue=args.max_queue),
                     pctx=pctx, draft_policy=draft_policy, faults=faults)
+    return eng
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
+    import jax
+    import numpy as np
+
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
+    eng = build_engine(args)
+    cfg, policy, faults, pctx = eng.cfg, eng.policy, eng.faults, eng.pctx
     layout = (f"paged block={eng.kvcfg.block_size} "
               f"pool={eng.num_blocks} blocks/layer "
               f"prefix_cache={not args.no_prefix_cache}"
@@ -290,15 +313,18 @@ def main():
               f"deadline_expirations={eng.deadline_expirations} "
               f"admission_failures={eng.admission_failures} "
               f"degrade_events={eng.degrade_events}")
+    failed = [r for r, v in sorted(outs.items()) if v.error]
     if faults is not None:
         fired = ", ".join(f"{s}@{n}" for s, n, _ in faults.fired) or "none"
         print(f"faults fired: {fired}")
-        failed = [r for r, v in sorted(outs.items()) if v.error]
-        if failed:
-            print(f"  failed rids: {failed}")
+    if failed:
+        print(f"  failed rids: {failed}")
     for rid, v in sorted(outs.items())[:4]:
         print(f"  rid={rid}: {v[:10]}{'…' if len(v) > 10 else ''}")
+    # an injected fault is expected to fail requests; any other error is
+    # the run's failure
+    return 1 if failed and faults is None else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

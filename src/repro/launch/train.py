@@ -37,6 +37,9 @@ def main():
     args = ap.parse_args()
 
     import jax
+
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if jax.default_backend() == "tpu":
         os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " " + TPU_XLA_FLAGS
 

@@ -41,7 +41,11 @@ def linear(x: Array, w, stats: Optional[dict] = None, name: str = "",
         stats[name] = stats.get(name, 0.0) + s
     if isinstance(w, QuantizedTensor):
         return ttq_matmul(x, w, kcfg=kcfg, pctx=pctx, tp=tp).astype(x.dtype)
-    return jnp.einsum("...d,od->...o", x, w.astype(x.dtype))
+    # f32 result, then the cast: a contraction GSPMD splits over the model
+    # axis (column-parallel wo/wd/w2) then reduces its partial sums in f32,
+    # as the single-device dot does, instead of rounding each to bf16 first
+    return jnp.einsum("...d,od->...o", x, w.astype(x.dtype),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def init_linear(key, d_out: int, d_in: int, dtype=jnp.bfloat16, scale: float | None = None):

@@ -891,9 +891,8 @@ def moe_a2a(cfg: ModelConfig, p, x: Array, stats_on: bool, prefix: str, pctx):
                           model_axis=pctx.model_axis, data_axes=pctx.data_axes)
         return y, (st if stats_on else {})
 
-    from repro.parallel.compat import shard_map
-    y, st = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)(x, pr)
+    y, st = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)(x, pr)
     return y, st
 
 
@@ -908,8 +907,7 @@ def moe_apply_a2a(cfg: ModelConfig, p, x: Array, stats, prefix: str, *,
     to zero (standard); gates renormalized locally.
     """
     e = cfg.moe
-    from repro.parallel.compat import axis_size
-    tp = axis_size(model_axis)
+    tp = jax.lax.axis_size(model_axis)
     my = jax.lax.axis_index(model_axis)
     B, S, D = x.shape
     x2 = x.reshape(-1, D)

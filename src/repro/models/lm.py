@@ -223,7 +223,7 @@ def decode_many(cfg: ModelConfig, params, state, token, pos, done, remaining,
                 key, poison=None, *, K: int, max_len: int,
                 temperature: float = 0.0, eos_token: int = -1,
                 detect_faults: bool = False, pctx=None, kvcfg=None,
-                kcfg=None):
+                kcfg=None, return_logits: bool = False):
     """Fused multi-token decode: ``lax.scan`` over ``K`` decode steps keeping
     sampling, EOS detection, per-slot done-masking, budget accounting, and
     position advance entirely on device — one host transfer per K tokens
@@ -258,6 +258,10 @@ def decode_many(cfg: ModelConfig, params, state, token, pos, done, remaining,
     to NaN post-projection, exercising the exact detection path a real
     numerical fault would take.  Both default off, preserving the original
     signature and program for every existing caller.
+
+    ``return_logits`` appends every step's f32 logits (K, B, V) to the
+    output tuple — the same program with one more output, for checks that
+    compare the served numbers (``DeviceRunner.first_decode_logits``).
     """
     def step_fn(carry, _):
         st, tok, p, dn, rem, k = carry
@@ -279,15 +283,18 @@ def decode_many(cfg: ModelConfig, params, state, token, pos, done, remaining,
         stop = (nxt == eos_token) | (p >= max_len) | (rem <= 0)
         dn = dn | (live & stop)
         ys = (nxt, live, flt) if detect_faults else (nxt, live)
+        if return_logits:
+            ys += (logits.astype(jnp.float32),)
         return (st, nxt[:, None], p, dn, rem, k), ys
 
     carry = (state, token, pos, done, remaining, key)
     carry, ys = jax.lax.scan(step_fn, carry, None, length=K)
+    out = (ys[0].T, ys[1].T)
     if detect_faults:
-        toks, valid, flts = ys
-        return (toks.T, valid.T, flts.any(axis=0)), carry
-    toks, valid = ys
-    return (toks.T, valid.T), carry
+        out += (ys[2].any(axis=0),)
+    if return_logits:
+        out += (ys[-1],)
+    return out, carry
 
 
 def verify_window(cfg: ModelConfig, params, state, tokens, pos, *, pctx=None,

@@ -1,6 +1,4 @@
-from .compat import shard_map
 from .ctx import ParallelCtx
 from .rules import param_sharding, shard_params, state_sharding
 
-__all__ = ["ParallelCtx", "param_sharding", "shard_map", "shard_params",
-           "state_sharding"]
+__all__ = ["ParallelCtx", "param_sharding", "shard_params", "state_sharding"]

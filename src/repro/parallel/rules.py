@@ -77,6 +77,15 @@ def spec_for_path(path_str: str, leaf_ndim: int, model_axis: str = "model",
     return P(*([None] * leaf_ndim))                     # replicate by default
 
 
+def tp_role(path_str: str, model_axis: str = "model") -> Optional[str]:
+    """Tensor-parallel role of the 2-D (d', d) weight at ``path_str``:
+    'row' (output features on the model axis), 'col' (input features) or
+    None (replicated) — the ``tp`` hint of ``kernels.ops``' TP wrappers."""
+    row, col = (list(spec_for_path(path_str, 2, model_axis, stacked=False))
+                + [None, None])[:2]
+    return "row" if row == model_axis else "col" if col == model_axis else None
+
+
 def _axis_size(mesh, axes) -> int:
     if axes is None:
         return 1
@@ -106,14 +115,15 @@ _QT_FIELDS = ("wint", "packed", "scale", "zero", "dinv", "B", "A")
 def _qt_child_specs(base: P, model_axis: str):
     """Derive per-child specs for a QuantizedTensor from its 2-D weight spec.
 
-    base = (row, col) of the dequantized weight; wint/packed/scale/zero share
-    it (packed/scale cols are d/8, d/g slices of the same layout); dinv lives
-    on the input dim (col); B on rows, A on cols.
+    base = (row, col) of the (d', d) fp weight.  wint/packed/scale/zero are
+    stored K-major — (d, d'), (d/per, d'), (d/g, d') — so they take the
+    transposed spec (col, row); dinv lives on the input dim (col); B on
+    rows, A on cols.
     """
     row, col = (list(base) + [None, None])[:2]
     return {
-        "wint": P(row, col), "packed": P(row, col), "scale": P(row, col),
-        "zero": P(row, col), "dinv": P(col), "B": P(row, None), "A": P(None, col),
+        "wint": P(col, row), "packed": P(col, row), "scale": P(col, row),
+        "zero": P(col, row), "dinv": P(col), "B": P(row, None), "A": P(None, col),
     }
 
 

@@ -295,9 +295,15 @@ class FusedRequantPlan:
                 qcfg = dataclasses.replace(qcfg, layout="row")
             # eff.rank is part of the key: members with low-rank factors
             # concatenate their (d', r)/(r, d) B/A stacks, so mixed ranks
-            # (per-layer rank overrides) must land in separate families
+            # (per-layer rank overrides) must land in separate families;
+            # on a mesh so is the TP role, which fixes the family's layout
+            tp = None
+            if self.pctx is not None:
+                from repro.parallel.rules import tp_role
+                experts = leaf.ndim - 2 - ("stack" in ps) > 0
+                tp = "ep" if experts else tp_role(ps, self.pctx.model_axis)
             key = (dp, d, qcfg, eff.acfg, eff.method, eff.packed, has_ba,
-                   eff.rank)
+                   eff.rank, tp)
             self.families.setdefault(key, []).append(mem)
 
         jax.tree_util.tree_map_with_path(lambda p, l: visit(p, l) or None,
@@ -337,14 +343,15 @@ class FusedRequantPlan:
 
     def _run_family(self, key, Ws, Ss, countf, Bs, As):
         """ONE device program: stack → D → (W−BA)∘D → quantize → split."""
-        from repro.core.qdq import pack_bits
-        from repro.core.ttq import QuantizedTensor
+        from repro.core.ttq import QuantizedTensor, pack_weight, packable
         from .registry import get_quantizer
-        dp, d, qcfg, eff_acfg, method, packed_on, has_ba, _rank = key
+        dp, d, qcfg, eff_acfg, method, packed_on, has_ba, _rank, tp = key
         members = self.families[key]
         qz = get_quantizer(method)
-        W = jnp.concatenate([w.reshape(-1, dp, d).astype(jnp.float32)
-                             for w in Ws], axis=0)              # (N, d', d)
+        # the stack keeps the weights' own dtype: the quantizers cast per
+        # tile, so a bf16 family never holds an f32 copy of itself
+        W = jnp.concatenate([w.reshape(-1, dp, d) for w in Ws],
+                            axis=0)                              # (N, d', d)
         S = jnp.concatenate([s.reshape(-1, d) for s in Ss], axis=0)
         D = jax.vmap(lambda s: qz.diag(s, countf, eff_acfg, d))(S)   # (N, d)
         if has_ba:
@@ -352,27 +359,34 @@ class FusedRequantPlan:
                                  for b in Bs], axis=0)
             A = jnp.concatenate([a.reshape(-1, a.shape[-2], d)
                                  for a in As], axis=0)
-            W = W - jnp.einsum("nor,nrd->nod", B.astype(jnp.float32),
-                               A.astype(jnp.float32))
-        per = 32 // qcfg.bits if 32 % qcfg.bits == 0 else 0
-        packable = packed_on and per > 0 and d % per == 0
-        kernel_ok = (packable and self.policy.kernel.use_pallas
+            W = W.astype(jnp.float32) - jnp.einsum(
+                "nor,nrd->nod", B.astype(jnp.float32), A.astype(jnp.float32))
+        pack = packed_on and packable(qcfg.bits, d)
+        from repro.kernels import ops as kops
+        # on a mesh the kernel runs shard-locally over the family's own
+        # layout; an expert stack (or a split that breaks groups) takes
+        # the jnp quantizer, which GSPMD partitions
+        kernel_ok = (pack and self.policy.kernel.use_pallas
                      and qcfg.bits in (2, 4, 8) and not qcfg.symmetric
-                     and qcfg.nu == 1.0)
+                     and qcfg.nu == 1.0
+                     and kops.tp_quantize_ok(self.pctx, tp, W, bits=qcfg.bits,
+                                             group_size=qcfg.group_size))
         if kernel_ok:
-            from repro.kernels import ops as kops
-            kw = self.policy.kernel.quant_kw
-            pk, Sc, Z = jax.vmap(lambda w, dd: kops.ttq_quantize(
-                w, dd, bits=qcfg.bits, group_size=qcfg.group_size, **kw))(W, D)
+            pk, Sc, Z = kops.ttq_quantize_tp(
+                W, D, bits=qcfg.bits, group_size=qcfg.group_size,
+                pctx=self.pctx, tp=tp, **self.policy.kernel.quant_kw)
             wint = None
         else:
             from repro.core.awq import awq_quantize
             wint, Sc, Z = jax.vmap(
                 lambda w, dd: awq_quantize(w, dd, qcfg))(W, D)
-            pk = pack_bits(wint.astype(jnp.int32), qcfg.bits) if packable \
-                else None
-            if packable:
+            Sc, Z = Sc.swapaxes(1, 2), Z.swapaxes(1, 2)          # K-major
+            if pack:
+                pk = jax.vmap(lambda w: pack_weight(w, qcfg.bits))(wint)
                 wint = None
+            else:
+                pk = None
+                wint = wint.swapaxes(1, 2)
         dinv = (1.0 / D).astype(jnp.float32)
         out, off = [], 0
         for i, m in enumerate(members):
@@ -395,6 +409,15 @@ class FusedRequantPlan:
                 qt = constrain_qt(m.path_str, qt, self.pctx)
             out.append(qt)
         return out
+
+    def program_texts(self, params, stats, count, lowrank_tree=None):
+        """{family key: compiled text of its requant program} — what a check
+        reads to see which Pallas kernels a family runs (``tpu_custom_call``
+        ops on a TPU)."""
+        return {key: self._family_fns[key].lower(*self._gather(
+                    members, params, stats, count, lowrank_tree))
+                .compile().as_text()
+                for key, members in self.families.items()}
 
     def _eager_leaf(self, m: _Member, params, stats, count, lowrank_tree):
         """Per-leaf fallback for methods with a custom closed form."""

@@ -39,7 +39,7 @@ gets an independent calibration session; join with
 """
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any, Dict, Optional
 
 import jax
 
@@ -172,6 +172,15 @@ class QuantizedModel:
                     lowrank_tree=self.draft_lowrank_tree, pctx=self.pctx)
             self._plan_key = key
         return self._plan
+
+    def requant_program_texts(self) -> Dict[tuple, str]:
+        """Compiled text of each family program of the verify plan on the
+        session's current statistics ({} before the plan exists)."""
+        if self._plan is None:
+            return {}
+        stats, count = self.session.as_calib()
+        return self._plan.program_texts(self.params, stats, count,
+                                        self.lowrank_tree)
 
     def requantize(self, threshold: Optional[float] = None):
         """(Re)quantize from the session's current statistics.

@@ -15,7 +15,7 @@ The paper's lifecycle (Fig. 1b) as a slot-based engine:
                        blocks; with ``policy.kernel.use_pallas`` (or
                        ``EngineConfig.use_kernels``) every packed-weight
                        matmul dispatches the Pallas ttq_gemm (in-kernel
-                       unpack + dequant + D⁻¹ prologue)
+                       unpack + dequant)
 
 The engine is a thin facade over three parts (DESIGN.md §"Serving
 architecture"):
@@ -203,8 +203,10 @@ class TTQEngine:
                     f"reads whole written blocks")
         # weight-kernel dispatch: policy-driven, EngineConfig.use_kernels
         # wins when set.  Static too — it is baked into the jitted decode.
-        # The override is decode-only by design: the GEMM paths are bitwise
-        # identical, so flipping it never changes tokens, while the fused
+        # The override is decode-only by design: the GEMM paths feed the
+        # same operands to an f32-accumulated dot (bitwise identical in
+        # interpret mode; on the TPU equal up to accumulation order), so
+        # flipping it does not change tokens, while the fused
         # requant's Pallas ttq_quantize (a different rounding fusion — ±1
         # code ties) stays governed by the policy the QuantizedModel holds.
         self.kncfg = policy.kernel

@@ -160,14 +160,12 @@ class DeviceRunner:
                                        (self._state_shardings,
                                         rep, rep, rep, rep, rep))
         self._out_kw = out_kw
-        self._decode_jit = jax.jit(partial(
-            lm.decode_many, cfg, pctx=pctx, kvcfg=kvcfg, kcfg=kncfg,
-            K=K, max_len=ML, detect_faults=self.detect_faults,
-            temperature=ecfg.temperature, eos_token=ecfg.eos_token), **out_kw)
+        self._decode_jit = self._decode_program(K)
         # degradation ladder rung 2 (DESIGN.md §12): a K=1 decode program,
         # built lazily on the first degradation — small chunks bound the
         # wasted-work exposure when the pool is starving
         self._decode_small = None
+        self._decode_logits = None       # decode + logits, built on demand
         # self-speculative decode (DESIGN.md §11): K draft/verify windows of
         # W drafted tokens per dispatch; one program alongside decode_many —
         # the engine picks per block by passing (or not) a draft tree
@@ -482,19 +480,63 @@ class DeviceRunner:
 
     # ----------------------------------------------------------------- decode
 
+    def _decode_program(self, K: int, return_logits: bool = False):
+        """The jitted ``lm.decode_many`` of ``K`` steps, with this runner's
+        model, mesh, KV/kernel configs, fault detection and output
+        shardings (the logits, when returned, replicated)."""
+        ecfg, out_kw = self.ecfg, self._out_kw
+        if return_logits and out_kw:
+            ys, carry = out_kw["out_shardings"]
+            out_kw = {"out_shardings": (ys + (self._rep,), carry)}
+        return jax.jit(partial(
+            lm.decode_many, self.cfg, pctx=self.pctx, kvcfg=self.kvcfg,
+            kcfg=self.kncfg, K=K, max_len=ecfg.max_len,
+            detect_faults=self.detect_faults, temperature=ecfg.temperature,
+            eos_token=ecfg.eos_token, return_logits=return_logits), **out_kw)
+
     def _small_decode_jit(self):
         """Lazy K=1 decode program for degradation-ladder rung 2 — one
         compile at the first degradation, cached (and counted) afterwards,
         so an oscillating ladder never grows the jit caches."""
         if self._decode_small is None:
-            ecfg = self.ecfg
-            self._decode_small = jax.jit(partial(
-                lm.decode_many, self.cfg, pctx=self.pctx, kvcfg=self.kvcfg,
-                kcfg=self.kncfg, K=1, max_len=ecfg.max_len,
-                detect_faults=self.detect_faults,
-                temperature=ecfg.temperature, eos_token=ecfg.eos_token),
-                **self._out_kw)
+            self._decode_small = self._decode_program(1)
         return self._decode_small
+
+    def _decode_args(self, params):
+        args = (params, self.state, self.cur_tok, self.pos, self.done,
+                self.remaining, self.key)
+        return args + (self._poison,) if self.detect_faults else args
+
+    def first_decode_logits(self, params):
+        """f32 logits (B, V) of the next decode step over every slot, from
+        the decode program :meth:`decode_block` runs plus a logits output;
+        the slots' state is left as it was (a check reads the served
+        numbers without serving a token)."""
+        if self._decode_logits is None:
+            self._decode_logits = self._decode_program(
+                max(1, self.ecfg.decode_chunk), return_logits=True)
+        ys, _ = self._decode_logits(*self._decode_args(params))
+        return ys[-1][0]
+
+    def prefill_logits(self, params, prompts):
+        """f32 last-token logits (n, V) of ``prompts`` from the admission
+        prefill program (one batch, right-padded to the longest prompt);
+        nothing is written to the slots."""
+        plens = np.asarray([len(p) for p in prompts], np.int32)
+        toks = np.zeros((len(prompts), int(plens.max())), np.int32)
+        for i, p in enumerate(prompts):
+            toks[i, :len(p)] = p
+        logits, _, _ = self._prefill_jit(params, {"tokens": jnp.asarray(toks)},
+                                         max_len=self.ecfg.max_len)
+        return jnp.take_along_axis(
+            logits, jnp.asarray(plens - 1)[:, None, None], axis=1)[:, 0]
+
+    def decode_program_text(self, params) -> str:
+        """Compiled text of the decode program :meth:`decode_block` runs on
+        ``params`` — what a check reads to see which Pallas kernels it holds
+        (``tpu_custom_call`` ops on a TPU)."""
+        return self._decode_jit.lower(
+            *self._decode_args(params)).compile().as_text()
 
     def decode_block(self, params, draft_params=None, small_chunk=False):
         """Run one fused decode dispatch over every slot.

@@ -28,7 +28,7 @@ from repro.models import lm
 from repro.models.config import ModelConfig
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          compress_state_init, compressed_psum, cosine_schedule)
-from repro.parallel import ParallelCtx, compat, param_sharding, shard_map
+from repro.parallel import ParallelCtx, param_sharding
 
 P = jax.sharding.PartitionSpec
 
@@ -147,7 +147,7 @@ def make_compressed_dp_step(cfg: ModelConfig, tcfg: TrainConfig,
             grads, err_new = compressed_psum(grads, pctx.data_axes, err)
             n = 1
             for a in pctx.data_axes:
-                n *= compat.axis_size(a)
+                n *= jax.lax.axis_size(a)
             grads = jax.tree.map(lambda g: g / n, grads)
             lr = cosine_schedule(opt_state["step"], tcfg.warmup,
                                  tcfg.total_steps, tcfg.opt.lr)
@@ -160,7 +160,7 @@ def make_compressed_dp_step(cfg: ModelConfig, tcfg: TrainConfig,
         ospec = jax.tree.map(lambda _: P(), opt_state)
         espec = jax.tree.map(lambda _: P(), err)
         bspec = jax.tree.map(lambda _: P(dp), batch)
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(pspec, ospec, espec, bspec),
             out_specs=(pspec, ospec, espec,

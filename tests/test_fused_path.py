@@ -189,7 +189,7 @@ def test_fused_requant_pallas_quantize_kernel(prefilled):
     rounding-boundary ties (the test_kernels tolerance), and a full-model
     decode over the kernel-quantized tree stays finite and kernel-served."""
     from repro.core import FUSED_KERNELS
-    from repro.core.qdq import unpack_bits
+    from repro.core.ttq import unpack_weight
 
     params, stats, count = prefilled
     pol = ttq_policy(bits=4, group_size=32, rank=0, packed=True,
@@ -202,8 +202,8 @@ def test_fused_requant_pallas_quantize_kernel(prefilled):
     for a, b in zip(_qts(ref), _qts(fused)):
         assert b.packed is not None
         n_packed += 1
-        ua = np.asarray(unpack_bits(a.packed, a.in_features, a.bits))
-        ub = np.asarray(unpack_bits(b.packed, b.in_features, b.bits))
+        ua = np.asarray(unpack_weight(a.packed, a.in_features, a.bits))
+        ub = np.asarray(unpack_weight(b.packed, b.in_features, b.bits))
         assert (ua != ub).mean() < 2e-3          # boundary ties only
         assert np.abs(ua.astype(int) - ub.astype(int)).max() <= 1
         np.testing.assert_allclose(np.asarray(a.scale), np.asarray(b.scale),

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.qdq import unpack_bits
+from repro.core.ttq import unpack_weight
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(42)
@@ -34,8 +34,8 @@ def test_ttq_quantize_kernel(T, d, dp, bits, g):
     W, D, _ = _data(T, d, dp)
     pk, S, Z = ops.ttq_quantize(W, D, bits=bits, group_size=g)
     pk_r, S_r, Z_r = ref.ttq_quantize_ref(W, D, bits=bits, group_size=g)
-    u = np.asarray(unpack_bits(pk, d, bits))
-    ur = np.asarray(unpack_bits(pk_r, d, bits))
+    u = np.asarray(unpack_weight(pk, d, bits))
+    ur = np.asarray(unpack_weight(pk_r, d, bits))
     assert (u != ur).mean() < 2e-3          # boundary ties only
     assert np.abs(u.astype(int) - ur.astype(int)).max() <= 1
     np.testing.assert_allclose(np.asarray(S), np.asarray(S_r), rtol=1e-5)

@@ -23,7 +23,8 @@ def test_moe_a2a_equals_dense(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from repro.models import lm, ModelConfig, MoECfg
 from repro.parallel import ParallelCtx
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh(2, 2)
 cfg = ModelConfig(name='t', family='moe', n_layers=2, d_model=64, n_heads=4,
       n_kv_heads=2, d_ff=0, vocab=128,
       moe=MoECfg(n_experts=4, top_k=2, d_ff_expert=64, n_shared=1,
@@ -53,7 +54,8 @@ from repro.models import ModelConfig
 from repro.training import Trainer, TrainConfig
 from repro.data import DataConfig, token_stream
 from repro.parallel import ParallelCtx
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh(2, 4)
 pctx = ParallelCtx(mesh=mesh, data_axes=('data',))
 cfg = ModelConfig(name='t', family='dense', n_layers=2, d_model=64, n_heads=8,
                   n_kv_heads=4, d_ff=128, vocab=64)
@@ -161,10 +163,11 @@ def test_qt_specs_children_consistent(seed, path, model, lowrank, expert):
     """QuantizedTensor child specs stay mutually consistent and, with a mesh,
     always divide the child shapes.
 
-    Consistency: wint/packed/scale/zero share the (row, col) placement; dinv
-    sits on the col placement; B on rows, A on cols (mesh=None form — the
-    divisibility fallback may legitimately drop an axis for one child whose
-    narrower dim doesn't divide, e.g. scale's d/g columns)."""
+    Consistency: wint/packed/scale/zero (stored K-major, input dim first)
+    share one placement; dinv sits on the input-dim placement; B on the
+    output dim, A on the input dim (mesh=None form — the divisibility
+    fallback may legitimately drop an axis for one child whose narrower dim
+    doesn't divide, e.g. scale's d/g rows)."""
     rng = np.random.default_rng(seed)
     lead = (1,) if "stack" in path else ()
     bits, per = 4, 8
@@ -174,23 +177,23 @@ def test_qt_specs_children_consistent(seed, path, model, lowrank, expert):
     ex = (int(rng.choice([2, 4, 8])),) if expert else ()
     r = int(rng.integers(1, 9))
     shapes = {
-        "wint": None, "packed": (*lead, *ex, dp, d // per),
-        "scale": (*lead, *ex, dp, d // g), "zero": (*lead, *ex, dp, d // g),
+        "wint": None, "packed": (*lead, *ex, d // per, dp),
+        "scale": (*lead, *ex, d // g, dp), "zero": (*lead, *ex, d // g, dp),
         "dinv": (*lead, *ex, d),
         "B": (*lead, *ex, dp, r) if lowrank else None,
         "A": (*lead, *ex, r, d) if lowrank else None,
     }
     pure = qt_specs(path, shapes, "model")
     nd = len(shapes["packed"])
-    row_i, col_i = nd - 2, nd - 1
-    # shared (row, col) placement across the packed/scale/zero family
+    in_i, out_i = nd - 2, nd - 1                  # K-major: (d/per, d')
+    # shared placement across the packed/scale/zero family
     for k in ("scale", "zero"):
-        assert _placement(pure[k], row_i) == _placement(pure["packed"], row_i)
-        assert _placement(pure[k], col_i) == _placement(pure["packed"], col_i)
+        assert _placement(pure[k], in_i) == _placement(pure["packed"], in_i)
+        assert _placement(pure[k], out_i) == _placement(pure["packed"], out_i)
     # dinv rides the input dim; B the output dim; A the input dim
-    assert _placement(pure["dinv"], nd - 2) == _placement(pure["packed"], col_i)
-    assert _placement(pure["B"], row_i) == _placement(pure["packed"], row_i)
-    assert _placement(pure["A"], col_i) == _placement(pure["packed"], col_i)
+    assert _placement(pure["dinv"], nd - 2) == _placement(pure["packed"], in_i)
+    assert _placement(pure["B"], nd - 2) == _placement(pure["packed"], out_i)
+    assert _placement(pure["A"], nd - 1) == _placement(pure["packed"], in_i)
     # leading (layer, expert) dims agree everywhere
     for i in range(nd - 2):
         want = _placement(pure["packed"], i)
@@ -217,9 +220,9 @@ C.SHAPES = {'train_4k': (64, 8, 'train'), 'prefill_32k': (64, 4, 'prefill'),
             'decode_32k': (64, 8, 'decode'), 'long_500k': (128, 1, 'decode')}
 import repro.launch.steps as S
 S.SHAPES = C.SHAPES
-from repro.launch.mesh import make_ctx
+from repro.launch.mesh import auto_mesh, make_ctx
 from repro.configs import get
-mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+mesh = auto_mesh((2, 2, 2), ('pod', 'data', 'model'))
 pctx = make_ctx(mesh)
 for arch in ['gemma_7b', 'deepseek_v2_lite_16b', 'mamba2_1p3b']:
     cfg = get(arch, smoke=True)

@@ -74,7 +74,7 @@ def test_moe_per_expert_quantization():
     qp = quantize_params(params, stats, pol, count=count)
     qt = qp["stack"][0]["u0"]["mlp"]["experts"]["wg"]
     assert isinstance(qt, QuantizedTensor)
-    assert qt.wint.shape == (2, 4, 48, 64)               # (L, E, F, D)
+    assert qt.wint.shape == (2, 4, 64, 48)               # (L, E, D, F)
     assert qt.dinv.shape == (2, 4, 64)                   # per-expert D!
     # per-expert diagonals differ (different token subsets)
     d0, d1 = np.asarray(qt.dinv[0, 0]), np.asarray(qt.dinv[0, 1])

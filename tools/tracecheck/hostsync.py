@@ -42,6 +42,8 @@ _HOST_METHODS = {"item", "tolist", "block_until_ready"}
 _ARRAY_PREFIXES = ("jnp.", "jax.numpy.", "jax.lax.", "lax.", "jax.nn.",
                    "jax.random.")
 _ARRAY_CALLS = {"jax.device_put", "jax.eval_shape"}
+# jax/lax calls that return Python ints (mesh metadata), not arrays
+_STATIC_CALLS = {"axis_size"}
 
 
 def _text_dotted(expr: ast.AST) -> Optional[str]:
@@ -65,7 +67,7 @@ def _is_array_call(expr: ast.Call) -> bool:
         return True
     if any(d.startswith(p) for p in _ARRAY_PREFIXES):
         tail = d.rsplit(".", 1)[-1]
-        return tail not in _META_ATTRS
+        return tail not in _META_ATTRS | _STATIC_CALLS
     return False
 
 
