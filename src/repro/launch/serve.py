@@ -22,7 +22,11 @@ quantize to packed int codes and every decode matmul dispatches the Pallas
 whose activation diagonal drifted ≥ T (relative L2) re-quantize, the rest
 reuse their previous packed tensors.  The end-of-run summary reports the
 gate's skip counts and the requantization wall time next to
-``host_syncs/token``.
+``host_syncs/token``, and a ``telemetry:`` line from the engine's spans and
+counters (``serving/telemetry.py``): the mean wait per first token split
+into intake, queue and ingestion, the mean decode steps per dispatch, the
+host time per round outside blocking reads, and the rounds at each rung
+of the degradation ladder.
 
 ``--kv-paged`` switches the slot caches to the block-paged pool (DESIGN.md
 §8): ``--kv-block-size`` sets the block granularity, ``--kv-pool-blocks``
@@ -293,6 +297,18 @@ def main(argv=None):
           f"{lat['ttft_p99'] * 1e3:.1f} ms, itl p50/p99 "
           f"{lat['itl_p50'] * 1e3:.1f}/{lat['itl_p99'] * 1e3:.1f} ms "
           f"({lat['n_streams']} streams)")
+    tm = eng.telemetry.snapshot()
+    first, rounds = max(tm["first_tokens"], 1), max(tm["rounds"], 1)
+    host = (tm["engine.round.total_s"] + tm["server.drain.total_s"]
+            - tm["runner.sync.total_s"] - tm["requant.health.total_s"])
+    print(f"telemetry: intake/queue/ingest "
+          f"{tm['intake_wait_s'] / first * 1e3:.1f}/"
+          f"{tm['queue_wait_s'] / first * 1e3:.1f}/"
+          f"{tm['ingest_s'] / first * 1e3:.1f} ms per first token, "
+          f"mean K {tm['decode_steps'] / max(tm['decode_dispatches'], 1):.2f}"
+          f", round host {host / rounds * 1e3:.2f} ms, rounds at ladder "
+          f"rungs 0-3 " + "/".join(str(tm[f"rounds_at_rung_{i}"])
+                                   for i in range(4)))
     if eng.ecfg.prefill_chunk > 0 or eng.ecfg.max_queue > 0:
         print(f"slo: prefill_chunks={eng.prefill_chunks} "
               f"queue_rejections={eng.queue_rejections} "

@@ -39,6 +39,7 @@ gets an independent calibration session; join with
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, Optional
 
 import jax
@@ -61,7 +62,8 @@ class QuantizedModel:
                  lowrank: Any = _AUTO, fused: bool = True,
                  double_buffer: bool = False, pctx=None,
                  draft_policy: Optional[QuantPolicy] = None,
-                 health_gate: Optional[GuardConfig] = None):
+                 health_gate: Optional[GuardConfig] = None,
+                 telemetry=None):
         self.params = params
         self.policy = policy
         self.acfg = acfg
@@ -89,7 +91,9 @@ class QuantizedModel:
         # snapshots; rejections keep serving the last-good tree
         self.health_gate = health_gate
         self.requant_rejections = 0
-        self.last_health_drift = 0.0
+        # a serving engine's Telemetry (serving/telemetry.py): spans of
+        # calibration, the family programs and the health gate's host read
+        self.telemetry = telemetry
         self._fault_hook = None          # designated injection site: called
                                          # with the candidate tree pre-
                                          # validation (serving/faults.py)
@@ -125,8 +129,13 @@ class QuantizedModel:
         """Fold one prefill's activation statistics into the session.
         ``provenance`` (request ids) rides into the quarantine log when a
         guarded session rejects the update."""
-        self.session.update(stats, tokens, provenance=provenance)
+        with self._span("quant.calibrate"):
+            self.session.update(stats, tokens, provenance=provenance)
         return self
+
+    def _span(self, name: str):
+        tm = self.telemetry
+        return tm.span(name) if tm is not None else contextlib.nullcontext()
 
     def _active(self) -> bool:
         from .registry import get_quantizer
@@ -281,16 +290,17 @@ class QuantizedModel:
             drifts = plan.drift(stats, count, last_D)
             only, n_requant, n_skip = plan.gate(drifts, threshold,
                                                 set(qt_by_path))
-        tree = plan.run(self.params, stats, count, lowrank,
-                        only=only, reuse=qt_by_path)
+        with self._span("requant.run"):
+            tree = plan.run(self.params, stats, count, lowrank,
+                            only=only, reuse=qt_by_path)
         if self._fault_hook is not None:
             tree = self._fault_hook(tree)
         if self.health_gate is not None:
             prev = {p: qt.dinv for p, qt in qt_by_path.items()
                     if qt.dinv is not None}
-            ok, drift = qt_health(tree, prev,
+            with self._span("requant.health"):
+                ok, _ = qt_health(tree, prev,
                                   self.health_gate.requant_max_drift)
-            self.last_health_drift = drift
             if not ok:
                 self.requant_rejections += 1
                 return None, 0, 0

@@ -56,6 +56,9 @@ from repro.quant import guards as _guards
 
 from .runner import DeviceRunner
 from .scheduler import GenResult, Request, Scheduler, pick_decode_chunk
+from .telemetry import Telemetry, rid_meta
+
+_RUNGS = tuple(f"rounds_at_rung_{i}" for i in range(4))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -213,13 +216,15 @@ class TTQEngine:
         if ecfg.use_kernels is not None:
             self.kncfg = dataclasses.replace(self.kncfg,
                                              use_pallas=ecfg.use_kernels)
+        # spans and counters of every part (serving/telemetry.py)
+        self.telemetry = tm = Telemetry()
         # runner first: with a mesh, the fp parameter tree is committed to
         # its sharded layout through the runner (the one component allowed
         # to allocate device memory — TC402/TC405) BEFORE the quant model
         # captures it, so every requant reads already-local weight shards
         self.runner = DeviceRunner(cfg, ecfg, self.kvcfg, kncfg=self.kncfg,
                                    pctx=pctx, key=key,
-                                   num_blocks=self.num_blocks)
+                                   num_blocks=self.num_blocks, telemetry=tm)
         self.params = params = self.runner.place_params(params)
         # robustness layer (DESIGN.md §12): one GuardConfig drives the
         # session's update validation, the model's requant health gate, the
@@ -232,11 +237,10 @@ class TTQEngine:
             session=CalibrationSession(halflife=ecfg.stats_halflife,
                                        guard=guard),
             double_buffer=ecfg.double_buffer, pctx=pctx,
-            draft_policy=self.draft_policy, health_gate=guard)
+            draft_policy=self.draft_policy, health_gate=guard, telemetry=tm)
         self.scheduler = Scheduler(
             ecfg, exact_buckets=cfg.family in ("hybrid", "ssm"),
-            kvcfg=self.kvcfg, num_blocks=self.num_blocks)
-        self.requant_wall_s = 0.0       # dispatch time spent requantizing
+            kvcfg=self.kvcfg, num_blocks=self.num_blocks, telemetry=tm)
         # fault injection (serving/faults.py): deterministic, seeded faults
         # at named sites; the injector may supply a virtual clock so
         # deadline scenarios replay bit-for-bit
@@ -257,9 +261,8 @@ class TTQEngine:
 
     def _requantize(self):
         thr = self.ecfg.requant_threshold
-        t0 = time.perf_counter()
-        tree = self.qmodel.requantize(threshold=thr if thr >= 0 else None)
-        self.requant_wall_s += time.perf_counter() - t0
+        with self.telemetry.span("requant"):
+            tree = self.qmodel.requantize(threshold=thr if thr >= 0 else None)
         if tree is not None:
             self.scheduler.note_requant()
 
@@ -347,6 +350,12 @@ class TTQEngine:
     @property
     def host_syncs(self):
         return self.runner.host_syncs
+
+    @property
+    def requant_wall_s(self) -> float:
+        """Host seconds spent in requantization calls (the ``requant``
+        span's total)."""
+        return self.telemetry.get("requant.total_s")
 
     @property
     def compiled_programs(self) -> int:
@@ -467,7 +476,7 @@ class TTQEngine:
     # --------------------------------------------------------------- serving
 
     def submit(self, prompt, max_new: int = 16, frames=None,
-               deadline_s=None, priority: int = 0) -> int:
+               deadline_s=None, priority: int = 0, recv_t=None) -> int:
         """Queue a request; rejects prompts the engine cannot admit, and
         raises :class:`~repro.serving.scheduler.QueueFull` at
         ``EngineConfig.max_queue`` depth.
@@ -478,10 +487,12 @@ class TTQEngine:
         forever.  Defaults to ``EngineConfig.deadline_s``.  ``priority``
         (lower = more urgent) picks the SLO class: admission order,
         eviction order and chunked-ingestion order all honour it
-        (DESIGN.md §13)."""
+        (DESIGN.md §13).  ``recv_t`` (engine clock) is when a front end
+        received the request, if earlier than now."""
         return self.scheduler.submit(prompt, max_new, frames=frames,
                                      deadline_s=deadline_s,
-                                     now=self._clock(), priority=priority)
+                                     now=self._clock(), priority=priority,
+                                     recv_t=recv_t)
 
     def set_stream_callbacks(self, on_token=None, on_finish=None):
         """Install streaming callbacks: ``on_token(rid, tok, t)`` fires for
@@ -507,7 +518,8 @@ class TTQEngine:
         on device *before* their blocks can be reallocated."""
         slots = self.scheduler.pending_releases
         if slots:
-            self.runner.release_slots(slots)
+            with self.telemetry.span("sched.release"):
+                self.runner.release_slots(slots)
             self.scheduler.pending_releases = []
 
     def admit(self):
@@ -518,17 +530,20 @@ class TTQEngine:
         finishes *at admission* (budget of 1, EOS or capacity on its first
         token) frees its slot immediately, and the next planning round hands
         that slot to the next queued request instead of stranding it."""
+        tm = self.telemetry
         while True:
-            groups = self.scheduler.plan_admissions()
+            with tm.span("sched.plan"):
+                groups = self.scheduler.plan_admissions(now=self._clock())
             self._flush_releases()   # preempted slots → sink before prefill
             if not groups:
                 break
             for group in groups:
+                rids = tuple(r.rid for r in group.requests)
                 # encdec frames ride each Request; the runner stages them
                 # on device (the facade never allocates arrays)
-                first, fin, stats = self.runner.admit_group(self.params,
-                                                            group)
-                rids = tuple(r.rid for r in group.requests)
+                with tm.span("runner.prefill", rids=rid_meta(rids)):
+                    first, fin, stats = self.runner.admit_group(self.params,
+                                                                group)
                 tokens = group.tokens
                 if self.faults is not None:
                     stats, tokens = self.faults.calib_site(stats, tokens,
@@ -555,10 +570,14 @@ class TTQEngine:
         sees the whole prompt across chunks exactly as it would from one
         monolithic prefill.  The final chunk arms the lane and emits the
         request's first token."""
-        plans = self.scheduler.plan_prefill_chunks()
+        tm = self.telemetry
+        with tm.span("sched.plan"):
+            plans = self.scheduler.plan_prefill_chunks()
         for plan in plans:
-            first, fin, stats = self.runner.prefill_chunk(self.params, plan)
             rids = (plan.req.rid,)
+            with tm.span("runner.chunk", rids=rid_meta(rids)):
+                first, fin, stats = self.runner.prefill_chunk(self.params,
+                                                              plan)
             tokens = float(self.ecfg.prefill_chunk)
             if self.faults is not None:
                 stats, tokens = self.faults.calib_site(stats, tokens, rids)
@@ -606,31 +625,42 @@ class TTQEngine:
         Returns True while the engine still has work to drive — including
         rounds where every runnable request is waiting out a retry backoff
         (no decode dispatched, but ``run_all`` must keep stepping)."""
-        now = self._clock()
-        if self.faults is not None:
-            self.faults.on_step(self)
-        self.scheduler.expire_deadlines(now)
-        self._flush_releases()       # deadline-evicted slots → sink
-        self.admit()
-        self._run_chunks()           # budgeted chunked-prefill dispatches
-        self._update_ladder()
-        if not self.scheduler.decode_slots():
-            # mid-chunked-prefill lanes are work even though nothing decodes
-            return (bool(self.scheduler.prefilling)
-                    or self.scheduler.has_deferred_work())
-        draft = None if self.degrade_level >= 1 else self.draft_params
-        if self.faults is not None and self.runner.detect_faults:
-            slots = self.faults.decode_site(self.scheduler.slot_req,
-                                            self.scheduler._round)
-            self.runner.set_poison(slots)
-        toks, valid, done, fault = self.runner.decode_block(
-            self.decode_params, draft, small_chunk=self.degrade_level >= 2)
-        self.scheduler.record_block(toks, valid, done, fault=fault,
-                                    now=self._clock())
-        self._flush_releases()       # freed blocks must not be written again
-        if self.scheduler.should_requant():
-            self._requantize()
-        return True
+        tm = self.telemetry
+        with tm.span("engine.round"):
+            tm.count("rounds")
+            now = self._clock()
+            if self.faults is not None:
+                self.faults.on_step(self)
+            with tm.span("sched.plan"):
+                self.scheduler.expire_deadlines(now)
+            self._flush_releases()       # deadline-evicted slots → sink
+            self.admit()
+            self._run_chunks()           # budgeted chunked-prefill dispatches
+            self._update_ladder()
+            tm.count(_RUNGS[self.degrade_level])
+            if not self.scheduler.decode_slots():
+                # mid-chunked-prefill lanes are work even though nothing
+                # decodes
+                return (bool(self.scheduler.prefilling)
+                        or self.scheduler.has_deferred_work())
+            draft = None if self.degrade_level >= 1 else self.draft_params
+            if self.faults is not None and self.runner.detect_faults:
+                slots = self.faults.decode_site(self.scheduler.slot_req,
+                                                self.scheduler._round)
+                self.runner.set_poison(slots)
+            with tm.span("runner.decode"):
+                toks, valid, done, fault = self.runner.decode_block(
+                    self.decode_params, draft,
+                    small_chunk=self.degrade_level >= 2)
+            with tm.span("sched.record"):
+                landed = self.scheduler.record_block(toks, valid, done,
+                                                     fault=fault,
+                                                     now=self._clock())
+            tm.count("decode_tokens", landed)
+            self._flush_releases()   # freed blocks must not be written again
+            if self.scheduler.should_requant():
+                self._requantize()
+            return True
 
     def run_all(self, max_iters: int = 10_000) -> Dict[int, GenResult]:
         """Drive until all submitted requests finish; returns {rid: tokens}.

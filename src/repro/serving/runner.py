@@ -20,8 +20,8 @@ hits prefill only the prompt *tail*, gathering the cached prefix from the
 pool), and ``release_slots`` points finished/preempted slots at the sink
 block 0 so their done-lane writes can never corrupt reallocated blocks.
 
-``host_syncs`` counts blocking device→host transfers — the number
-``benchmarks/bench_engine.py`` reports per generated token.
+Blocking device→host reads are spans of the engine's telemetry
+(``serving/telemetry.py``); ``host_syncs`` is its count of them.
 """
 from __future__ import annotations
 
@@ -36,6 +36,7 @@ from repro.quant.api import _path_str
 
 from .blocks import SINK
 from .sampling import sample
+from .telemetry import Telemetry, rid_meta
 
 
 def _write_slots(batched, src, slots):
@@ -103,8 +104,9 @@ def _gather_pool(pool, ptab):
 
 class DeviceRunner:
     def __init__(self, cfg, ecfg, kvcfg, *, kncfg=None, pctx=None, key=None,
-                 num_blocks: int = 0):
+                 num_blocks: int = 0, telemetry: Telemetry | None = None):
         self.cfg, self.ecfg, self.kvcfg, self.pctx = cfg, ecfg, kvcfg, pctx
+        self.tm = telemetry if telemetry is not None else Telemetry()
         self.kncfg = kncfg                      # KernelConfig: packed-weight
         self.key = key if key is not None else jax.random.PRNGKey(0)
         self.paged = kvcfg is not None and kvcfg.paged
@@ -117,7 +119,6 @@ class DeviceRunner:
         self.cur_tok = jnp.zeros((B, 1), jnp.int32)
         self.done = jnp.ones((B,), bool)        # empty slot = done lane
         self.remaining = jnp.zeros((B,), jnp.int32)
-        self.host_syncs = 0                     # blocking device→host copies
         # fault isolation (DESIGN.md §12): with guards on, decode checks
         # per-step logit finiteness on device and reports a per-slot fault
         # mask; the poison lane is the deterministic injection site
@@ -214,6 +215,11 @@ class DeviceRunner:
         self.key = jax.device_put(self.key, self._rep)
         if self._poison is not None:
             self._poison = jax.device_put(self._poison, self._rep)
+
+    @property
+    def host_syncs(self) -> int:
+        """Blocking device→host transfers (the telemetry's count)."""
+        return self.tm.get("host_syncs")
 
     @property
     def compiled_programs(self) -> int:
@@ -318,12 +324,13 @@ class DeviceRunner:
         self.pos = self.pos.at[idx].set(jnp.asarray(plens_h))  # decode
         self.cur_tok = self.cur_tok.at[idx].set(first[:, None])  # overwrites
         self.remaining = self.remaining.at[idx].set(jnp.asarray(budget_h))
-        first_h = jax.device_get(first)  # tracecheck: ok[TC103] one designed
-        #                                  sync per admission group
+        with self.tm.span("runner.sync", rids=rid_meta(r.rid for r in reqs)):
+            first_h = jax.device_get(first)  # tracecheck: ok[TC103] one
+            #                      designed sync per admission group
+        self.tm.count("host_syncs")
         fin_h = ((plens_h >= ecfg.max_len) | (budget_h <= 0)
                  | (first_h == ecfg.eos_token))
         self.done = self.done.at[idx].set(jnp.asarray(fin_h))
-        self.host_syncs += 1
         self._repin()                    # admission writes → canonical layout
         return first_h, fin_h
 
@@ -574,14 +581,21 @@ class DeviceRunner:
             fault = None
         (self.state, self.cur_tok, self.pos, self.done, self.remaining,
          self.key) = carry
-        self.host_syncs += 1
         fetch = ((toks, valid, self.done) if fault is None
                  else (toks, valid, self.done, fault))
-        out = jax.device_get(fetch)              # the ONE designed sync/chunk
+        with self.tm.span("runner.sync"):
+            out = jax.device_get(fetch)          # the ONE designed sync/chunk
         if fault is None:
             out = out + (None,)
+        W = self.ecfg.speculate_k
+        B, cols = toks.shape
+        steps = cols // (W + 1) if spec else cols   # the K of the program
+        tm = self.tm
+        tm.count("host_syncs")
+        tm.count("decode_dispatches")
+        tm.count("decode_steps", steps)
+        tm.count("decode_slot_steps", steps * B)
         if spec:
-            W = self.ecfg.speculate_k
             v = np.asarray(out[1]).reshape(out[1].shape[0], -1, W + 1)
             live = v[:, :, 0]                     # a live window always emits
             emitted = v.sum(axis=2)

@@ -38,6 +38,8 @@ import itertools
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
+from .telemetry import Telemetry
+
 
 class QueueFull(RuntimeError):
     """``submit`` rejected: the intake queue is at ``max_queue`` capacity.
@@ -92,6 +94,10 @@ class Request:
     priority: int = 0               # class: lower = more urgent
     prefilled: int = 0              # chunked prefill: tokens resident on device
     tok_times: list = dataclasses.field(default_factory=list)  # emit stamps
+    # ---- lifecycle stamps (engine clock; serving/telemetry.py) ----
+    recv_t: float = 0.0             # received by the front end (≤ submit_t)
+    admit_t: float = 0.0            # slot claimed (the latest claim)
+    first_t: Optional[float] = None  # first token ever (kept across retries)
 
     def __post_init__(self):
         if not self.orig_len:
@@ -149,8 +155,9 @@ class ChunkPlan:
 
 class Scheduler:
     def __init__(self, ecfg, exact_buckets: bool = False, kvcfg=None,
-                 num_blocks: int = 0):
+                 num_blocks: int = 0, telemetry: Optional[Telemetry] = None):
         self.ecfg = ecfg
+        self.telemetry = telemetry if telemetry is not None else Telemetry()
         # recurrent state would absorb pad tokens — prefill at exact length
         self.exact_buckets = exact_buckets
         self.queue: deque = deque()
@@ -203,7 +210,7 @@ class Scheduler:
 
     def submit(self, prompt, max_new: int = 16, frames=None,
                deadline_s: Optional[float] = None, now: float = 0.0,
-               priority: int = 0) -> int:
+               priority: int = 0, recv_t: Optional[float] = None) -> int:
         prompt = list(prompt)
         limit = self.max_prompt_len
         if len(prompt) > limit:
@@ -234,7 +241,9 @@ class Scheduler:
                    if deadline_s is None else deadline_s)
         self.queue.append(Request(rid, prompt, max_new, frames=frames,
                                   deadline_s=dl, submit_t=float(now),
-                                  priority=int(priority)))
+                                  priority=int(priority),
+                                  recv_t=float(now if recv_t is None
+                                               else recv_t)))
         return rid
 
     # ------------------------------------------------------------- streaming
@@ -243,7 +252,13 @@ class Scheduler:
         """Land one generated token: append to the request's output, stamp
         the emission time (TTFT/ITL metrics) and fire the streaming
         callback.  Every token-producing path funnels through here so
-        ``len(out) == len(tok_times)`` holds everywhere."""
+        ``len(out) == len(tok_times)`` holds everywhere.  A request's first
+        token ever splits its wait in the telemetry — once, though a
+        lane-fault retry emits from the start again."""
+        if req.first_t is None:
+            req.first_t = float(now)
+            self.telemetry.first_token(req.recv_t, req.submit_t, req.admit_t,
+                                       req.first_t)
         req.out.append(int(tok))
         req.tok_times.append(float(now))
         if self.on_token is not None:
@@ -385,10 +400,10 @@ class Scheduler:
         self._recent_victims.add(req.rid)
         return req
 
-    def plan_admissions(self) -> List[AdmissionGroup]:
+    def plan_admissions(self, now: float = 0.0) -> List[AdmissionGroup]:
         """Pop queued requests into free slots in FIFO order, then group the
         round's admissions by (bucket, prefix_len) — each group is one
-        prefill dispatch.
+        prefill dispatch.  ``now`` (engine clock) stamps each claim.
 
         Paged: each admission reserves its blocks upfront (prompt +
         generation budget, minus prefix-cache hits).  On pool exhaustion a
@@ -472,6 +487,7 @@ class Scheduler:
             self._starve.pop(req.rid, None)
             self.queue.remove(req)
             req.admit_seq = next(self._admit_seq)
+            req.admit_t = float(now)
             slot = free.pop(0)
             self.slot_req[slot] = req       # claimed now: a preemption later
             picked.append((slot, req))      # in this round must not free it

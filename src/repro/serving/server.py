@@ -11,7 +11,9 @@ touches queues, futures and semaphores:
 
 * **submit** — a coroutine enqueues a command and awaits a future; the
   worker performs the actual ``engine.submit`` and resolves the future with
-  the rid (or the typed rejection).
+  the rid (or the typed rejection).  The request's receipt is stamped on
+  the engine clock before backpressure, so the engine's telemetry tells
+  the wait for the worker apart from the wait in its queue.
 * **stream** — the engine's ``on_token`` / ``on_finish`` callbacks (fired
   on the worker thread inside ``step``) forward events into the consumer's
   ``asyncio.Queue`` via ``loop.call_soon_threadsafe`` — the one documented
@@ -126,9 +128,11 @@ class TTQServer:
         Raises :class:`RequestFailed` if the request lands with a terminal
         error; a cancellation just ends the stream."""
         rid, q, done = None, None, False
+        recv_t = self.engine._clock()    # receipt, before any backpressure
         await self._acquire()
         try:
-            rid, q = await self._open(prompt, max_new, priority, deadline_s)
+            rid, q = await self._open(prompt, max_new, priority, deadline_s,
+                                      recv_t)
             while True:
                 ev = await q.get()
                 if isinstance(ev, GenResult):
@@ -145,9 +149,11 @@ class TTQServer:
         """Await a whole generation; returns its :class:`GenResult` (error
         results return rather than raise — inspect ``.error``)."""
         rid, done = None, False
+        recv_t = self.engine._clock()    # receipt, before any backpressure
         await self._acquire()
         try:
-            rid, q = await self._open(prompt, max_new, priority, deadline_s)
+            rid, q = await self._open(prompt, max_new, priority, deadline_s,
+                                      recv_t)
             while True:
                 ev = await q.get()
                 if isinstance(ev, GenResult):
@@ -163,13 +169,13 @@ class TTQServer:
             raise RuntimeError("server not started")
         await self._sem.acquire()
 
-    async def _open(self, prompt, max_new, priority, deadline_s):
+    async def _open(self, prompt, max_new, priority, deadline_s, recv_t):
         """Hand the submit to the worker; await the rid."""
         fut = self._loop.create_future()
         q: asyncio.Queue = asyncio.Queue()
         self._cmds.put(("submit", list(prompt),
                         dict(max_new=max_new, priority=priority,
-                             deadline_s=deadline_s), fut, q))
+                             deadline_s=deadline_s, recv_t=recv_t), fut, q))
         self._wake.set()
         return await fut, q
 
@@ -188,17 +194,20 @@ class TTQServer:
         pending, park on the wake event otherwise.  The ONLY thread that
         touches the engine after ``start()``."""
         eng = self.engine
+        tm = eng.telemetry
         eng.set_stream_callbacks(self._on_token, self._on_finish)
         try:
             while True:
-                self._drain_cmds()
+                with tm.span("server.drain"):
+                    self._drain_cmds()
                 sched = eng.scheduler
                 if sched.has_work() or sched.has_deferred_work():
                     eng.step()
                 elif self._stop_evt.is_set():
                     break
                 else:
-                    self._wake.wait(self.poll_s)
+                    with tm.span("server.park"):
+                        self._wake.wait(self.poll_s)
                     self._wake.clear()
         except BaseException as e:   # tracecheck: ok[TC406] worker crash
             #   boundary: land the failure in every open stream instead of

@@ -94,7 +94,7 @@ ENGINE_ATTRS = [
     "deadline_expirations", "admission_failures", "degrade_level",
     "submit", "cancel", "admit", "step", "run_all",
     "queue_depth", "queue_rejections", "prefill_chunks",
-    "latency_percentiles", "set_stream_callbacks",
+    "latency_percentiles", "set_stream_callbacks", "telemetry",
 ]
 SERVING_EXPORTS = ["BlockAllocator", "DeviceRunner", "EngineConfig",
                    "Fault", "FaultInjector", "GenResult", "QueueFull",
