@@ -10,7 +10,8 @@
 * ``kv_paged_decode_attention`` — the block-paged variant: flash-decoding
                             over a per-slot block table into a shared
                             (num_blocks, Hkv, block_size, ·) quantized pool
-                            (scalar-prefetched table lookups per S-tile).
+                            (each slot's live blocks only, copied through
+                            the scalar-prefetched table).
 
 ``ops`` wraps all with jnp fallbacks; ``ref`` holds the pure-jnp oracles the
 tests assert against (interpret=True on CPU, compiled on TPU).

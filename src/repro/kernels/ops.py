@@ -62,9 +62,11 @@ def kv_paged_decode_attention(q, kq, ks, vq, vs, block_table, cur_pos, *,
 
     ``kq/ks/vq/vs`` are the (NB, Hkv, block_size, ·) pools; ``block_table``
     (B, nblk) maps each slot's logical blocks to physical pool blocks.  The
-    Pallas path streams one physical block per S-tile through a
-    scalar-prefetched table lookup; the fallback gathers the table's view
-    and runs the contiguous jnp oracle (identical math).
+    Pallas path reads only each slot's live blocks (none for a slot parked
+    on the sink block), many blocks and every KV head per compute tile,
+    copied from HBM through the scalar-prefetched table; the fallback
+    gathers the table's view and runs the contiguous jnp oracle (identical
+    math on the live slots).
     """
     if use_pallas and bits in _KV_BITS:
         return _ttq_paged_attn_pallas(q, kq, ks, vq, vs, block_table, cur_pos,

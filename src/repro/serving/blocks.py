@@ -40,7 +40,9 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Dict, List, Tuple
 
-SINK = 0            # physical block 0: write sink, never allocated
+# physical block 0: write sink, never allocated; the paged attention read
+# skips every lane whose block-table row starts with it
+from repro.kernels.ttq_attn import SINK  # noqa: F401
 
 
 def chain_hashes(tokens, block_size: int, n_blocks: int) -> List[int]:

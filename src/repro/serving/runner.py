@@ -584,9 +584,9 @@ class DeviceRunner:
         fetch = ((toks, valid, self.done) if fault is None
                  else (toks, valid, self.done, fault))
         with self.tm.span("runner.sync"):
-            out = jax.device_get(fetch)          # the ONE designed sync/chunk
-        if fault is None:
-            out = out + (None,)
+            # the ONE designed sync/chunk (positions too, for page counts)
+            *out, pos = jax.device_get(fetch + (self.pos,))
+        out = tuple(out) + ((None,) if fault is None else ())
         W = self.ecfg.speculate_k
         B, cols = toks.shape
         steps = cols // (W + 1) if spec else cols   # the K of the program
@@ -595,6 +595,15 @@ class DeviceRunner:
         tm.count("decode_dispatches")
         tm.count("decode_steps", steps)
         tm.count("decode_slot_steps", steps * B)
+        if self.paged:
+            # the pages the paged attention read covers: each lane live
+            # after the block, up to its position (the read skips the rest)
+            bs = self.kvcfg.block_size
+            nblk = self.ecfg.max_len // bs
+            active = ~np.asarray(out[2])
+            tm.count("attn_pages_live", steps * int(
+                np.minimum(pos[active] // bs + 1, nblk).sum()))
+            tm.count("attn_pages_table", steps * B * nblk)
         if spec:
             v = np.asarray(out[1]).reshape(out[1].shape[0], -1, W + 1)
             live = v[:, :, 0]                     # a live window always emits

@@ -58,6 +58,8 @@ COUNTERS = (
     "decode_steps",         # decode steps they ran (K of the program chosen)
     "decode_slot_steps",    # steps × slots
     "decode_tokens",        # decode tokens landed in requests
+    "attn_pages_live",      # paged KV: pages the Pallas attention reads
+    "attn_pages_table",     # paged KV: steps × slots × block-table width
     "host_syncs",           # blocking device→host transfers
     "first_tokens",         # requests that emitted their first token
     "intake_wait_s",        # Σ submit − receipt (server intake)

@@ -54,10 +54,8 @@ def _dense_ref(params, kv_dtype):
 
 # ----------------------------------------------------------- paged kernel
 
-@pytest.mark.parametrize("bits,group_size", [(8, 0), (8, 16), (4, 0), (4, 16)])
-def test_paged_kernel_matches_ref(bits, group_size):
-    """Pallas paged flash-decoding (scalar-prefetched block table) vs the
-    gather-then-contiguous jnp oracle."""
+def _small_table_case(bits, group_size):
+    """Two lanes over a 9-block pool, 4-block rows ending in the sink."""
     B, Hkv, H, Dh, bs, NB = 2, 2, 4, 32, 16, 9
     pk = jnp.asarray(RNG.standard_normal((NB, Hkv, bs, Dh)).astype("float32"))
     pv = jnp.asarray(RNG.standard_normal((NB, Hkv, bs, Dh)).astype("float32"))
@@ -66,13 +64,71 @@ def test_paged_kernel_matches_ref(bits, group_size):
     bt = jnp.asarray([[3, 1, 4, SINK], [5, 2, SINK, SINK]], jnp.int32)
     pos = jnp.asarray([41, 17], jnp.int32)
     q = jnp.asarray(RNG.standard_normal((B, H, 1, Dh)).astype("float32"))
-    o_ref = kv_paged_attn_ref(q, kq, ks, vq, vs, bt, pos, bits=bits,
-                              group_size=group_size)
-    o_pl = kv_paged_decode_attention(q, kq, ks, vq, vs, bt, pos, bits=bits,
-                                     group_size=group_size)
-    np.testing.assert_allclose(np.asarray(o_ref, np.float32),
-                               np.asarray(o_pl, np.float32),
-                               rtol=1e-5, atol=1e-5)
+    return q, (kq, ks, vq, vs), bt, pos, [True, True]
+
+
+def _tile_edge_case(Hkv, G, bits, group_size, sink_lane):
+    """The cells' head shapes at head size 128 over a 40-page table: lanes
+    at position 0, at the last token of the first compute tile, at the
+    first of the next, and at the table's end (40 is no multiple of the
+    tile's pages); rows past a lane's pages point at the sink, as the
+    engine leaves them.  ``sink_lane`` adds a released lane: position
+    ``max_len``, its whole row the sink."""
+    from repro.kernels.ttq_attn import pages_per_tile
+
+    Dh, bs, nblk = 128, 16, 40
+    B = 5 if sink_lane else 4
+    NB = B * nblk + 1
+    pk = jnp.asarray(RNG.standard_normal((NB, Hkv, bs, Dh)).astype("float32"))
+    pv = jnp.asarray(RNG.standard_normal((NB, Hkv, bs, Dh)).astype("float32"))
+    pools = (*quantize_kv(pk, bits=bits, group_size=group_size),
+             *quantize_kv(pv, bits=bits, group_size=group_size))
+    T = pages_per_tile(pools, nblk, Dh) * bs
+    assert T < nblk * bs and (nblk * bs) % T        # a partial last tile
+    pos = [0, T - 1, T, nblk * bs - 1] + ([nblk * bs] if sink_lane else [])
+    bt = np.full((B, nblk), SINK, np.int32)
+    ids = RNG.permutation(np.arange(1, NB)).reshape(B, nblk)
+    for b, p in enumerate(pos[:4]):
+        bt[b, :p // bs + 1] = ids[b, :p // bs + 1]
+    q = jnp.asarray(RNG.standard_normal((B, Hkv * G, 1, Dh)).astype("float32"))
+    return (q, pools, jnp.asarray(bt), jnp.asarray(pos, jnp.int32),
+            [True] * 4 + [False] * sink_lane)
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(dict(bits=8, group_size=0), id="8-0"),
+    pytest.param(dict(bits=8, group_size=16), id="8-16"),
+    pytest.param(dict(bits=4, group_size=0), id="4-0"),
+    pytest.param(dict(bits=4, group_size=16), id="4-16"),
+    pytest.param(dict(heads=(8, 3)), id="hkv8-g3-tile-edges"),
+    pytest.param(dict(heads=(4, 12)), id="hkv4-g12-tile-edges"),
+    pytest.param(dict(heads=(1, 12)), id="hkv1-g12-tile-edges"),
+    pytest.param(dict(heads=(4, 12), bits=4, group_size=16),
+                 id="hkv4-g12-int4-g16"),
+    pytest.param(dict(heads=(8, 3), soft_cap=2.0), id="hkv8-g3-soft-cap"),
+    pytest.param(dict(heads=(4, 12), sink_lane=True), id="hkv4-g12-sink-lane"),
+])
+def test_paged_kernel_matches_ref(case):
+    """Pallas paged flash-decoding (live pages of the scalar-prefetched
+    block table, many pages and every KV head per tile) vs the
+    gather-then-contiguous jnp oracle; a released lane reads nothing and
+    writes a finite output."""
+    bits, gs = case.get("bits", 8), case.get("group_size", 0)
+    soft_cap = case.get("soft_cap", 0.0)
+    if "heads" in case:
+        q, pools, bt, pos, live = _tile_edge_case(
+            *case["heads"], bits, gs, case.get("sink_lane", False))
+    else:
+        q, pools, bt, pos, live = _small_table_case(bits, gs)
+    o_ref = kv_paged_attn_ref(q, *pools, bt, pos, bits=bits, group_size=gs,
+                              soft_cap=soft_cap)
+    o_pl = kv_paged_decode_attention(q, *pools, bt, pos, bits=bits,
+                                     group_size=gs, soft_cap=soft_cap)
+    o_pl = np.asarray(o_pl, np.float32)
+    assert np.isfinite(o_pl).all()
+    assert (o_pl[~np.asarray(live)] == 0).all()     # nothing read, 0 out
+    np.testing.assert_allclose(np.asarray(o_ref, np.float32)[live],
+                               o_pl[live], rtol=1e-5, atol=1e-5)
 
 
 def test_paged_gather_equals_contiguous():

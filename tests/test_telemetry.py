@@ -200,6 +200,28 @@ def test_decode_counters_read_the_program_that_ran(params, K):
     assert counters.decode_steps == s1["decode_steps"]
 
 
+def test_page_counters_read_the_positions_of_the_one_sync(params):
+    """One paged decode block adds steps × slots × table width to
+    attn_pages_table and each live lane's pages, up to its position after
+    the block, to attn_pages_live, from the one blocking read the block
+    makes anyway."""
+    import numpy as np
+
+    eng = _engine(params, kv_dtype="int8", kv_paged=True, kv_block_size=16)
+    eng.submit(list(range(1, 21)), max_new=30)   # one lane of two
+    eng.step()
+    s0 = eng.telemetry.snapshot()
+    eng.runner.decode_block(eng.decode_params)
+    s1 = eng.telemetry.snapshot()
+    pos = np.asarray(eng.runner.pos)
+    live = ~np.asarray(eng.runner.done)
+    assert live.sum() == 1 and pos[live][0] > 16     # two pages, one lane
+    assert s1["attn_pages_table"] - s0["attn_pages_table"] == 3 * 2 * 4
+    assert s1["attn_pages_live"] - s0["attn_pages_live"] == 3 * 2
+    assert s1["host_syncs"] - s0["host_syncs"] == 1
+    assert s1["runner.sync.n"] - s0["runner.sync.n"] == 1
+
+
 def test_ladder_rungs_counted_per_round(params):
     from repro.quant import GuardConfig
 

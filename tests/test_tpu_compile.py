@@ -145,3 +145,27 @@ def test_paged_decode_attention_compiles(one_chip):
     scale = ((nb, HKV, BLOCK, c.groups(DH)), jnp.float32)
     _compile(fn, [((SLOTS, H, 1, DH), jnp.bfloat16), code, scale, code, scale,
                   ((SLOTS, nblk), jnp.int32), ((SLOTS,), jnp.int32)], one_chip)
+
+
+@pytest.mark.parametrize("slots,max_len,hkv,g", [
+    pytest.param(8, 1024, 8, 3, id="minitron-4b.decode"),
+    pytest.param(16, 2048, 4, 12, id="starcoder2-15b-l8.admit"),
+])
+def test_paged_decode_attention_compiles_at_cell_shapes(one_chip, slots,
+                                                        max_len, hkv, g):
+    """The paged read at the benchmark cells' slots, positions and heads
+    (int8 pool, 16-token pages): one kernel, named for the decode program
+    check."""
+    from repro.core.kvquant import KVCacheConfig
+    from repro.kernels.ttq_attn import ttq_paged_decode_attention
+    c = KVCacheConfig(dtype="int8", paged=True, block_size=BLOCK)
+    nblk = max_len // BLOCK
+    nb = slots * nblk + 1
+    fn = functools.partial(ttq_paged_decode_attention, bits=c.bits,
+                           interpret=False)
+    code = ((nb, hkv, BLOCK, c.code_shape(DH)), c.code_dtype)
+    scale = ((nb, hkv, BLOCK, c.groups(DH)), jnp.float32)
+    text = _compile(fn, [((slots, hkv * g, 1, DH), jnp.bfloat16), code, scale,
+                         code, scale, ((slots, nblk), jnp.int32),
+                         ((slots,), jnp.int32)], one_chip)
+    assert _smoke().kernel_calls(text) == {"ttq_paged_decode_attention": 1}
